@@ -65,7 +65,7 @@ bench-index:
 bench-trace:
 	python -m benchmarks.run --suite trace --fast
 
-# traversal kernel family: host vs jit vs fused-pallas latency ladder,
+# traversal kernel family: host vs jit vs whole-chain latency ladder,
 # batched point-lookup throughput, per-kernel roofline attribution
 bench-kernels:
 	python -m benchmarks.run --suite kernels

@@ -76,7 +76,7 @@ def _trace_suite(sf: int, fast: bool) -> list[dict]:
 
 def _kernels_suite(sf: int, fast: bool) -> list[dict]:
     """Traversal kernel family: single-query latency ladder (host matcher vs
-    per-hop jit vs fused pallas path) over start selectivity, batched
+    per-hop jit vs whole-chain program) over start selectivity, batched
     point-lookup throughput (launch amortization across >=64 concurrent
     queries), and achieved-vs-roof bandwidth of the DeviceMatchPattern
     kernel spans from the engine's fenced trace export."""
@@ -141,6 +141,21 @@ def _finish(all_rows: list[dict], args) -> None:
         print(f"# baselines -> {path}", file=sys.stderr)
 
 
+def place_compile_cache() -> str:
+    """Leave JAX's persistent compile cache where JAX_COMPILATION_CACHE_DIR
+    puts it; else keep it at a fixed path in the checkout (the path is part
+    of the cache key, so it must not move between runs). Returns the cache
+    directory."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return os.environ["JAX_COMPILATION_CACHE_DIR"]
+    import jax
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    path = os.path.join(root, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--sf", type=int, default=1)
@@ -171,6 +186,7 @@ def main() -> None:
                          "from this run's rows (the perf-regression gate's "
                          "committed reference; see benchmarks.regression)")
     args = ap.parse_args()
+    place_compile_cache()
 
     from . import m2bench_suite as m2
     from .kernels_bench import kernel_microbench

@@ -12,6 +12,7 @@
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Sequence
 
 import jax
@@ -23,8 +24,6 @@ from ..kernels.cosine_sim.ops import cosine_sim as _cosine_op
 from ..kernels.logreg.ops import logreg_grad as _logreg_op
 from ..kernels.matmul.ops import matmul as _matmul_op
 from .storage import DictColumn, RaggedColumn, Table
-
-_ON_TPU = jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -171,26 +170,28 @@ def similarity(x: jax.Array, y: jax.Array, *, mesh: Optional[Mesh] = None,
                    out_shardings=NamedSharding(mesh, P("data", "model")))(xs, ys)
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("iters", "lr", "l2", "use_kernel"))
+def _regression_loop(x, y, *, iters: int, lr: float, l2: float,
+                     use_kernel: bool | None):
+    def step(_, carry):
+        w, _ = carry
+        g, loss = _logreg_op(x, y, w, use_kernel=use_kernel)
+        return w - lr * (g + l2 * w), loss
+
+    w0 = jnp.zeros((x.shape[1],), jnp.float32)
+    return jax.lax.fori_loop(0, iters, step, (w0, jnp.float32(0)))
+
+
 def regression(x: jax.Array, y: jax.Array, *, iters: int = 100,
                lr: float = 0.5, l2: float = 1e-4,
                use_kernel: bool | None = None) -> tuple[jax.Array, jax.Array]:
     """REGRESSION: train a logistic-regression model with the fused
-    gradient kernel inside a lax loop. Returns (weights, final loss)."""
-    n, d = x.shape
-    w0 = jnp.zeros((d,), jnp.float32)
-
-    import functools
-
-    @functools.partial(jax.jit, static_argnames=("n_iters",))
-    def run(x_, y_, w_, n_iters):
-        def step(_, carry):
-            w, _ = carry
-            g, loss = _logreg_op(x_, y_, w, use_kernel=use_kernel)
-            return w - lr * (g + l2 * w), loss
-
-        return jax.lax.fori_loop(0, n_iters, step, (w_, jnp.float32(0)))
-
-    return run(x, y, w0, iters)
+    gradient kernel inside a lax loop. Returns (weights, final loss). One
+    jitted program per (shape, iters, lr, l2, use_kernel), shared by every
+    call."""
+    return _regression_loop(x, y, iters=int(iters), lr=float(lr),
+                            l2=float(l2), use_kernel=use_kernel)
 
 
 def regression_distributed(x: jax.Array, y: jax.Array, mesh: Mesh, *,
@@ -199,9 +200,6 @@ def regression_distributed(x: jax.Array, y: jax.Array, mesh: Mesh, *,
     """Data-parallel REGRESSION: rows sharded over 'data'; each shard
     computes its partial gradient, one psum per iteration (the paper's
     "aggregating contributions from each partition in parallel")."""
-    from jax.experimental.shard_map import shard_map
-    from ..kernels.logreg import logreg_grad_ref
-
     n, d = x.shape
     ndev = mesh.shape["data"]
     pad = (-n) % ndev
@@ -219,9 +217,9 @@ def regression_distributed(x: jax.Array, y: jax.Array, mesh: Mesh, *,
             loss = jax.lax.psum(lpart, "data") / n
             return g, loss
 
-        sharded = shard_map(local_grad, mesh=mesh,
-                            in_specs=(P("data", None), P("data"), P()),
-                            out_specs=(P(), P()))
+        sharded = jax.shard_map(local_grad, mesh=mesh,
+                                in_specs=(P("data", None), P("data"), P()),
+                                out_specs=(P(), P()))
 
         def step(carry, _):
             w, _ = carry

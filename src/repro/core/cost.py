@@ -60,11 +60,11 @@ def cost_device_match(n_push_v: int, n_push_e: int, n_vertices: int,
     """Device-resident pattern match (DeviceMatchPattern). Differs from
     ``cost_pattern`` in three ways: vertex predicate tables are pure columnar
     scans (no per-record fetch), edge predicate tables read only the
-    zone-candidate fraction of the edge column (the kernel's prefetch filter
-    skips dead chunks), and the per-record traversal work runs at vector
-    width. In exchange every launch window pays a fixed dispatch+sync
-    charge — per hop for the jit matcher (it syncs on the overflow flag each
-    hop), once for the fused chain (one end-of-chain sync)."""
+    zone-candidate fraction of the edge column (the chunk filter skips dead
+    chunks), and the per-record traversal work runs at vector width. In
+    exchange every launch window pays a fixed dispatch+sync charge — per
+    hop for the jit matcher (it syncs on the overflow flag each hop), once
+    for the whole-chain program (one end-of-chain sync)."""
     tables = (n_push_v * n_vertices * COST_CPU
               + n_push_e * max(zone_frac, 0.0) * n_edges * (COST_IO + COST_CPU))
     lam = sum(avg_deg ** (h + 1) for h in range(hops))

@@ -422,9 +422,9 @@ def _annotate_match_access(root: ph.PhysicalOp, db: Database) -> None:
 def _select_match_path(root: ph.PhysicalOp, db: Database, report: OptReport,
                        cache: dict) -> ph.PhysicalOp:
     """Third access path for pattern matching: cost-compare the host matcher
-    (``pattern.match``) against the device flavors — the fused Pallas chain
-    (zone-filtered predicate tables, one end-of-chain sync) and the per-hop
-    jit matcher — and lower the MatchPattern to a ``DeviceMatchPattern``
+    (``pattern.match``) against the device flavors — the whole-chain XLA
+    program (zone-filtered predicate tables, one end-of-chain sync) and the
+    per-hop jit matcher — and lower the MatchPattern to a ``DeviceMatchPattern``
     when a device plan wins. Only mask-free chain patterns on settled
     (no-pending-delta) graphs qualify; the frontier-size estimate gates out
     patterns whose padded capacity would not fit the static-shape budget."""
@@ -450,7 +450,7 @@ def _select_match_path(root: ph.PhysicalOp, db: Database, report: OptReport,
     cap = cost_mod.padded_capacity(peak)
     cost_host = _est_cost(mp, db, cache)
     best = None
-    for access in ("device-pallas", "device-jit"):
+    for access in ("device-chain", "device-jit"):
         # the node embeds the graph's *catalog* write epoch (base + lineage
         # carry), matching MatchPattern — g.epoch alone diverges after a
         # graph is replaced via db.add_graph and would collide signatures

@@ -9,12 +9,12 @@ accelerator glue. Two device flavors share the predicate-lowering code:
     dispatch per hop with a host overflow sync between hops, dense
     predicate tables built by full column scans. Compiled once per
     (capacity, graph-shape) and reused across queries.
-  * ``device_match(flavor="pallas")`` — the fused path
-    (:mod:`repro.kernels.traversal`): the whole chain is one jit'd program
-    (the Pallas kernel per hop on TPU, its jnp oracle on CPU), predicate
-    tables are built through zone-map skip-scans (predicate-dead chunks
-    are never read) and the chunk-survivor bitmap rides into the kernel as
-    a prefetch filter; the host syncs once at the end of the chain.
+  * ``device_match(flavor="chain")`` — the whole-chain path
+    (:mod:`repro.kernels.traversal`): the whole chain is one jit'd XLA
+    program on every backend, predicate tables are built through zone-map
+    skip-scans (predicate-dead chunks are never read) and the
+    chunk-survivor bitmap rides into each hop as a filter; the host syncs
+    once at the end of the chain.
 
 Both flavors are epoch-stamped against the graph: a snapshot taken before a
 write burst refuses to serve (pending deltas) or re-syncs (compacted) before
@@ -50,7 +50,7 @@ class StaleSnapshotError(ValueError):
 class _Counters:
     matches: int = 0            # device_match invocations
     recompiles: int = 0         # jit-path capacity doublings
-    retries: int = 0            # fused-path capacity doublings
+    retries: int = 0            # chain-path capacity doublings
     refreshes: int = 0          # snapshot re-syncs after epoch bumps
     stale_rejects: int = 0      # refused matches on pending deltas
     retry_caps: dict = dataclasses.field(default_factory=dict)
@@ -71,7 +71,7 @@ COUNTERS = _Counters()
 
 
 def metrics() -> dict:
-    """Telemetry registry source: matcher counters + fused-kernel launch
+    """Telemetry registry source: matcher counters + chain-program launch
     counters, one flat namespace (cumulative; the engine's per-query view
     comes from registry snapshot deltas)."""
     out = COUNTERS.metrics()
@@ -381,21 +381,23 @@ def _kernel_span_args(hops: int, capacity: int, n_vertices: int,
             "zone_chunks_total": kernel_ops.COUNTERS.chunks_total}
 
 
-def device_match(g: Graph, pplan, *, flavor: str = "pallas",
+def device_match(g: Graph, pplan, *, flavor: str = "chain",
                  initial_capacity: Optional[int] = None,
                  max_capacity: int = 1 << 24,
-                 use_kernel: Optional[bool] = None):
+                 use_kernel: bool = False):
     """Execute a chain PatternPlan on the device path and build the same
     graph-relation Table as ``pattern.match`` (vertex columns hold vids,
     edge columns hold tids; deferred predicates applied). Returns
     (rel, kernel_args) — the second element is the telemetry span payload.
-    ``flavor``: "pallas" (fused chain, zone-filtered tables) or "jit"
-    (per-hop ``DevicePatternMatcher``). Raises ``StaleSnapshotError`` on
+    ``flavor``: "chain" (one program per chain, zone-filtered tables) or
+    "jit" (per-hop ``DevicePatternMatcher``). ``use_kernel=True`` runs the
+    chain's hops through the Pallas kernel (tests only; see
+    ``repro.kernels.traversal.ops``). Raises ``StaleSnapshotError`` on
     pending deltas; callers degrade to the host matcher."""
     COUNTERS.matches += 1
     matcher = get_matcher(g)
     matcher.refresh()
-    prep = prepare_chain(g, pplan, zone=(flavor == "pallas"))
+    prep = prepare_chain(g, pplan, zone=(flavor == "chain"))
     if prep is None:
         raise ValueError(f"pattern {pplan.pattern.canonical()!r} is not a "
                          "chain; device path unavailable")

@@ -397,15 +397,15 @@ class DeviceMatchPattern(PhysicalOp):
     """Mask-free chain match executed on the accelerator — the third access
     path of the pattern operator, chosen by the optimizer off frontier-size
     and selectivity estimates. ``access`` selects the flavor:
-    ``device-pallas`` runs the fused traversal kernel family (zone-filtered
-    predicate tables, in-kernel compaction, one launch window per chain);
+    ``device-chain`` runs the whole chain as one XLA program (zone-filtered
+    predicate tables, per-hop compaction, one launch window per chain);
     ``device-jit`` runs the per-hop ``DevicePatternMatcher``. Falls back to
     the host matcher at runtime if the graph has grown pending deltas since
     planning (the device snapshot reads base CSRs only)."""
     kind = "DeviceMatchPattern"
 
     def __init__(self, graph: str, epoch: int, pplan,
-                 access: str = "device-pallas",
+                 access: str = "device-chain",
                  capacity: Optional[int] = None):
         super().__init__()
         self.graph = graph
@@ -430,7 +430,7 @@ class DeviceMatchPattern(PhysicalOp):
             # deltas: degrade to the host matcher, don't fail
             self.access = "host-fallback"
             return pattern_mod.match(g, self.pplan)
-        flavor = "jit" if self.access == "device-jit" else "pallas"
+        flavor = "jit" if self.access == "device-jit" else "chain"
         rel, kargs = pattern_jit.device_match(
             g, self.pplan, flavor=flavor, initial_capacity=self.capacity)
         self.last_kernel_args = kargs
@@ -1360,7 +1360,7 @@ def estimate(root: PhysicalOp, db: Database,
             # columnar scans, edge tables read the zone-candidate fraction
             # only, frontier work runs at vector width, and each launch
             # window pays a fixed dispatch+sync charge (per hop on the jit
-            # flavor, once on the fused flavor)
+            # flavor, once on the whole-chain flavor)
             g = db.graphs[n.graph]
             p = n.pplan
             chain = [p.pattern.vertices[0].var] + [e.dst for e in p.pattern.edges]

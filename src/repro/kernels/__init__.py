@@ -1,6 +1,6 @@
 """Pallas TPU kernels. Each subpackage ships <name>.py (pl.pallas_call +
-BlockSpec), ops.py (jit'd wrapper; interpret=True off-TPU), ref.py (pure-jnp
-oracle)."""
+BlockSpec), ops.py (dispatch, decided per call by ``backend.resolve``: the
+compiled kernel on TPU, the oracle elsewhere), ref.py (pure-jnp oracle)."""
 from .cosine_sim import cosine_sim, cosine_sim_ref
 from .embedding_bag import embedding_bag, embedding_bag_ref
 from .flash_attention import flash_attention, flash_attention_ref

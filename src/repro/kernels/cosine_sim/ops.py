@@ -1,17 +1,13 @@
 from __future__ import annotations
 
-import jax
-
+from ..backend import resolve
 from .cosine_sim import cosine_sim as _kernel
 from .ref import cosine_sim_ref
-
-_ON_TPU = jax.default_backend() == "tpu"
 
 
 def cosine_sim(x, y, *, bm: int = 128, bn: int = 128, bk: int = 128,
                use_kernel: bool | None = None):
-    if use_kernel is None:
-        use_kernel = _ON_TPU  # interpret-mode Pallas is for validation, not speed
-    if not use_kernel:
+    use, interpret = resolve(use_kernel)
+    if not use:
         return cosine_sim_ref(x, y)
-    return _kernel(x, y, bm=bm, bn=bn, bk=bk, interpret=not _ON_TPU)
+    return _kernel(x, y, bm=bm, bn=bn, bk=bk, interpret=interpret)

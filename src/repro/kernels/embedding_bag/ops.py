@@ -1,16 +1,12 @@
 from __future__ import annotations
 
-import jax
-
+from ..backend import resolve
 from .embedding_bag import embedding_bag as _kernel
 from .ref import embedding_bag_ref
 
-_ON_TPU = jax.default_backend() == "tpu"
-
 
 def embedding_bag(table, indices, weights=None, *, use_kernel: bool | None = None):
-    if use_kernel is None:
-        use_kernel = _ON_TPU  # interpret-mode Pallas is for validation, not speed
-    if not use_kernel:
+    use, interpret = resolve(use_kernel)
+    if not use:
         return embedding_bag_ref(table, indices, weights)
-    return _kernel(table, indices, weights, interpret=not _ON_TPU)
+    return _kernel(table, indices, weights, interpret=interpret)

@@ -1,18 +1,14 @@
 from __future__ import annotations
 
-import jax
-
+from ..backend import resolve
 from .flash_attention import flash_attention as _kernel
 from .ref import flash_attention_ref
-
-_ON_TPU = jax.default_backend() == "tpu"
 
 
 def flash_attention(q, k, v, lengths=None, *, causal: bool = True,
                     bq: int = 128, bk: int = 128, use_kernel: bool | None = None):
-    if use_kernel is None:
-        use_kernel = _ON_TPU  # interpret-mode Pallas is for validation, not speed
-    if not use_kernel:
+    use, interpret = resolve(use_kernel)
+    if not use:
         return flash_attention_ref(q, k, v, lengths, causal=causal)
     return _kernel(q, k, v, lengths, causal=causal, bq=bq, bk=bk,
-                   interpret=not _ON_TPU)
+                   interpret=interpret)

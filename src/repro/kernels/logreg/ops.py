@@ -1,16 +1,12 @@
 from __future__ import annotations
 
-import jax
-
+from ..backend import resolve
 from .logreg import logreg_grad as _kernel
 from .ref import logreg_grad_ref
 
-_ON_TPU = jax.default_backend() == "tpu"
-
 
 def logreg_grad(x, y, w, *, bn: int = 512, use_kernel: bool | None = None):
-    if use_kernel is None:
-        use_kernel = _ON_TPU  # interpret-mode Pallas is for validation, not speed
-    if not use_kernel:
+    use, interpret = resolve(use_kernel)
+    if not use:
         return logreg_grad_ref(x, y, w)
-    return _kernel(x, y, w, bn=bn, interpret=not _ON_TPU)
+    return _kernel(x, y, w, bn=bn, interpret=interpret)

@@ -1,21 +1,16 @@
-"""Public jit'd entry point for MULTIPLY. On non-TPU backends the Pallas
-kernel runs in interpret mode (CPU validation); on TPU it compiles to MXU
-tiles. ``use_kernel=False`` falls back to the jnp oracle (used by the
-benchmarks to isolate kernel effects)."""
+"""Public entry point for MULTIPLY: the compiled Pallas kernel on TPU, the
+jnp oracle elsewhere (``repro.kernels.backend.resolve``). ``use_kernel=False``
+forces the oracle (used by the benchmarks to isolate kernel effects)."""
 from __future__ import annotations
 
-import jax
-
+from ..backend import resolve
 from .matmul import matmul as _matmul_kernel_call
 from .ref import matmul_ref
-
-_ON_TPU = jax.default_backend() == "tpu"
 
 
 def matmul(x, y, *, bm: int = 128, bn: int = 128, bk: int = 128,
            use_kernel: bool | None = None):
-    if use_kernel is None:
-        use_kernel = _ON_TPU  # interpret-mode Pallas is for validation, not speed
-    if not use_kernel:
+    use, interpret = resolve(use_kernel)
+    if not use:
         return matmul_ref(x, y)
-    return _matmul_kernel_call(x, y, bm=bm, bn=bn, bk=bk, interpret=not _ON_TPU)
+    return _matmul_kernel_call(x, y, bm=bm, bn=bn, bk=bk, interpret=interpret)

@@ -1,14 +1,18 @@
-"""Dispatch + whole-chain drivers for the fused traversal kernels.
-
-``fused_hop``/``batched_hop`` follow the family convention: the Pallas
-kernel on TPU, the jnp oracle on CPU (interpret-mode Pallas is for
-validation, not speed), ``use_kernel`` to force either.
+"""Whole-chain drivers for device traversal.
 
 ``traverse_chain``/``batched_traverse`` run a whole chain pattern as ONE
 jit'd program — every hop's expansion, predicate evaluation, compaction and
 path re-join stays on device, and the host synchronizes once at the end
 (overflow flag + final count). That is the latency contrast with the
 per-hop ``DevicePatternMatcher``, which dispatches and syncs every hop.
+
+Each hop is ``ref.fused_hop_ref``, plain XLA, on every backend: this is
+the program the optimizer's ``device-chain`` access path runs. The Pallas
+hop kernel (``traversal.py``) is refused by the TPU compiler — its
+``(1, C)`` blocks break the 8x128 tiling rule, its scalar count output
+cannot live in VMEM, and its 1-D in-kernel gathers are not supported — so
+``use_kernel=True`` (the kernel under the Pallas interpreter off the TPU)
+is for the equivalence tests only.
 
 COUNTERS feed the telemetry registry through
 ``core.pattern_jit.metrics`` (cumulative — per-query deltas come from
@@ -23,10 +27,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..backend import on_tpu
 from . import ref
 from . import traversal as kern
-
-_ON_TPU = jax.default_backend() == "tpu"
 
 
 @dataclasses.dataclass
@@ -49,34 +52,6 @@ class _Counters:
 
 
 COUNTERS = _Counters()
-
-
-def fused_hop(row_ptr, col_idx, edge_id, frontier, fmask, member, edge_pred,
-              chunk_alive, *, capacity: int, chunk: int,
-              use_kernel: bool | None = None):
-    if use_kernel is None:
-        use_kernel = _ON_TPU  # interpret-mode Pallas is for validation, not speed
-    if not use_kernel:
-        return ref.fused_hop_ref(row_ptr, col_idx, edge_id, frontier, fmask,
-                                 member, edge_pred, chunk_alive,
-                                 capacity=capacity, chunk=chunk)
-    return kern.fused_hop(row_ptr, col_idx, edge_id, frontier, fmask, member,
-                          edge_pred, chunk_alive, capacity=capacity,
-                          chunk=chunk, interpret=not _ON_TPU)
-
-
-def batched_hop(row_ptr, col_idx, edge_id, frontiers, fmasks, member,
-                edge_pred, chunk_alive, *, capacity: int, chunk: int,
-                use_kernel: bool | None = None):
-    if use_kernel is None:
-        use_kernel = _ON_TPU
-    if not use_kernel:
-        return ref.batched_hop_ref(row_ptr, col_idx, edge_id, frontiers,
-                                   fmasks, member, edge_pred, chunk_alive,
-                                   capacity=capacity, chunk=chunk)
-    return kern.batched_hop(row_ptr, col_idx, edge_id, frontiers, fmasks,
-                            member, edge_pred, chunk_alive, capacity=capacity,
-                            chunk=chunk, interpret=not _ON_TPU)
 
 
 # ---------------------------------------------------------------------------
@@ -168,14 +143,12 @@ def _padded_csr(row_ptr, col_idx, edge_id, n_edges):
 
 def traverse_chain(row_ptr, col_idx, edge_id, n_vertices: int, n_edges: int,
                    start_nids, members, edge_preds, chunk_alives, *,
-                   capacity: int, chunk: int, use_kernel: bool | None = None):
+                   capacity: int, chunk: int, use_kernel: bool = False):
     """Run a whole chain in one jit'd program. ``members[h]`` /
     ``edge_preds[h]`` / ``chunk_alives[h]`` are per-hop tables (None =
     unconstrained). Returns (vcols, ecols, ok): trimmed np arrays of the
     matched path columns (hop order), or ``ok=False`` on capacity overflow
     (caller doubles and retries)."""
-    if use_kernel is None:
-        use_kernel = _ON_TPU
     rp, ci, ei = _padded_csr(row_ptr, col_idx, edge_id, n_edges)
     mem, epr, cal = _device_tables(n_vertices, n_edges, chunk, members,
                                    edge_preds, chunk_alives)
@@ -188,7 +161,8 @@ def traverse_chain(row_ptr, col_idx, edge_id, n_vertices: int, n_edges: int,
     fmask = jnp.zeros((capacity,), bool).at[:C0].set(True)
     vcols, ecols, count, ovf = _chain_device(
         rp, ci, ei, frontier, fmask, mem, epr, cal, capacity=capacity,
-        chunk=chunk, use_kernel=bool(use_kernel), interpret=not _ON_TPU)
+        chunk=chunk, use_kernel=use_kernel,
+        interpret=use_kernel and not on_tpu())
     COUNTERS.launches += 1
     COUNTERS.hops += len(mem)
     if bool(ovf):               # the chain's one host sync
@@ -201,14 +175,12 @@ def traverse_chain(row_ptr, col_idx, edge_id, n_vertices: int, n_edges: int,
 def batched_traverse(row_ptr, col_idx, edge_id, n_vertices: int,
                      n_edges: int, start_nids, members, edge_preds,
                      chunk_alives, *, capacity: int, chunk: int,
-                     use_kernel: bool | None = None):
+                     use_kernel: bool = False):
     """Point-lookup batching: ``start_nids`` is (B,) — one start vertex per
     query; all B queries advance through the chain in single launches.
     Returns (vcols, ecols, counts, ok): per-query path columns as
     (B, capacity) np arrays valid up to ``counts[q]``, or ``ok=False`` if
     any query overflowed."""
-    if use_kernel is None:
-        use_kernel = _ON_TPU
     rp, ci, ei = _padded_csr(row_ptr, col_idx, edge_id, n_edges)
     mem, epr, cal = _device_tables(n_vertices, n_edges, chunk, members,
                                    edge_preds, chunk_alives)
@@ -220,7 +192,8 @@ def batched_traverse(row_ptr, col_idx, edge_id, n_vertices: int,
     fmasks = jnp.zeros((B, capacity), bool).at[:, 0].set(True)
     vcols, ecols, counts, ovf = _batched_chain_device(
         rp, ci, ei, frontiers, fmasks, mem, epr, cal, capacity=capacity,
-        chunk=chunk, use_kernel=bool(use_kernel), interpret=not _ON_TPU)
+        chunk=chunk, use_kernel=use_kernel,
+        interpret=use_kernel and not on_tpu())
     COUNTERS.launches += 1
     COUNTERS.hops += len(mem)
     COUNTERS.batched_queries += int(B)

@@ -25,9 +25,14 @@ Layout notes (vs the per-hop jit matcher in ``core.pattern_jit``):
   * ``.at[].set(mode="drop")`` gives the compaction scatter: dead slots
     target index ``capacity`` (one past the block) and vanish.
 
-On CPU this runs under ``interpret=True`` for validation; wall-clock
-benchmarking of the fused layout uses the jnp oracle (see
-``benchmarks/traversal_bench.py`` for the framing).
+The TPU compiler refuses this kernel, so it runs only under
+``interpret=True`` and the engine runs ``ref.fused_hop_ref`` instead
+(``ops.py``). Compiled for a v5e it fails in three places: the ``(1, C)``
+in/out blocks break the 8x128 tiling rule unless B is 1 or a multiple of
+8; the scalar stores to the ``(1, 1)`` count output are refused in VMEM;
+and with that output in SMEM, the 1-D in-kernel gathers (``rp[...]``,
+``ci[pos]``, ``mem[...]``, ``ep[...]``) are refused ("Only 2D gather is
+supported"). The last needs a redesign of the gathers, not a repair.
 """
 from __future__ import annotations
 

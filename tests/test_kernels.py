@@ -90,3 +90,27 @@ def test_flash_matches_model_dense_attention():
                           interpret=True)
     ref = _dense_attention(q, k, v, lens, True)
     np.testing.assert_allclose(out, ref, rtol=3e-4, atol=3e-5)
+
+
+def test_engine_import_initialises_no_backend():
+    """Kernel dispatch picks its backend per call: importing the engine
+    must not initialise JAX (a process that only imports it must not take
+    the chip)."""
+    import os
+    import subprocess
+    import sys
+    code = ("import repro.core, repro.data.m2bench, repro.kernels\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr
+
+
+def test_resolve_picks_oracle_off_tpu_and_interpreter_on_request():
+    from repro.kernels.backend import resolve
+    assert jax.default_backend() != "tpu"
+    assert resolve(None) == (False, False)
+    assert resolve(True) == (True, True)
+    assert resolve(False) == (False, False)

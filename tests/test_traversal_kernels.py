@@ -105,7 +105,7 @@ def test_batched_hop_kernel_matches_ref():
 
 
 # ---------------------------------------------------------------------------
-# Property test: host == per-hop jit == fused pallas path, including
+# Property test: host == per-hop jit == whole-chain path, including
 # tombstone-then-compact write bursts and overflow-forcing capacities
 # ---------------------------------------------------------------------------
 
@@ -130,7 +130,7 @@ def test_three_way_equivalence(seed, vpred, wcut, delete_some):
                         enable_pushdown=True)
     host = _rows(match(g, plan))
     jit_rel, _ = device_match(g, plan, flavor="jit", initial_capacity=128)
-    pal_rel, kargs = device_match(g, plan, flavor="pallas",
+    pal_rel, kargs = device_match(g, plan, flavor="chain",
                                   initial_capacity=128)
     assert _rows(jit_rel) == host
     assert _rows(pal_rel) == host
@@ -146,7 +146,7 @@ def test_pallas_kernel_path_matches_host():
     plan = plan_pattern(g, pattern, phi, projected=set(),
                         force_reverse=False, enable_pushdown=True)
     host = _rows(match(g, plan))
-    rel, _ = device_match(g, plan, flavor="pallas", initial_capacity=128,
+    rel, _ = device_match(g, plan, flavor="chain", initial_capacity=128,
                           use_kernel=True)
     assert _rows(rel) == host
 
@@ -171,7 +171,7 @@ def test_pallas_overflow_retry_counts_capacities():
     plan = plan_pattern(g, pattern, {}, projected=set(),
                         force_reverse=False, enable_pushdown=True)
     before = COUNTERS.retries
-    rel, _ = device_match(g, plan, flavor="pallas", initial_capacity=128)
+    rel, _ = device_match(g, plan, flavor="chain", initial_capacity=128)
     assert COUNTERS.retries > before
     assert any(cap > 128 for cap in COUNTERS.retry_caps)
     assert _rows(rel) == _rows(match(g, plan))
@@ -196,7 +196,7 @@ def test_stale_snapshot_refused_then_refreshed():
     plan = plan_pattern(g, pattern, {}, projected=set(),
                         force_reverse=False, enable_pushdown=True)
     with pytest.raises(StaleSnapshotError):
-        device_match(g, plan, flavor="pallas")
+        device_match(g, plan, flavor="chain")
     g.compact()
     cols, _ = m.match_chain(np.arange(lo, hi), [None], [None])
     assert m.epoch == g.epoch > epoch0
@@ -220,8 +220,8 @@ def test_engine_lowers_selective_chain_to_device(db):
     dag = eng.optimized_plan(q)
     rendered = physical.explain(dag)
     assert "DeviceMatchPattern" in rendered
-    assert "via device-pallas" in rendered
-    assert any("access-path" in n and "device-pallas" in n
+    assert "via device-chain" in rendered
+    assert any("access-path" in n and "device-chain" in n
                for n in eng.last_report.notes())
     opt = eng.query(q)
     optimizer.DEVICE_MATCH = False
@@ -254,7 +254,7 @@ def test_device_query_registry_delta_and_explain(db):
     assert d.get("traversal_kernels.kernel.launches", 0) >= 1
     txt = eng.explain_last()
     assert "traversal kernels (this query):" in txt
-    assert "via device-pallas" in txt
+    assert "via device-chain" in txt
 
 
 def test_roofline_rows_from_profile_trace(db):
