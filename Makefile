@@ -61,12 +61,12 @@ bench-index:
 
 # telemetry smoke: one GCDIA reuse ladder traced end-to-end, Chrome-trace
 # JSON exported to experiments/trace_gcdia.json (schema-validated; open in
-# Perfetto), kernel roofline attribution, disabled-telemetry overhead guard
+# Perfetto), disabled-telemetry overhead guard
 bench-trace:
 	python -m benchmarks.run --suite trace --fast
 
 # traversal kernel family: host vs jit vs whole-chain latency ladder,
-# batched point-lookup throughput, per-kernel roofline attribution
+# batched point-lookup throughput
 bench-kernels:
 	python -m benchmarks.run --suite kernels
 

@@ -193,41 +193,6 @@ def analyze(rec: dict) -> dict:
     }
 
 
-def from_trace(events: list[dict]) -> list[dict]:
-    """Roofline attribution of GCDA kernel spans from a Chrome trace export
-    (``telemetry.TraceCollector.to_chrome()["traceEvents"]``). Telemetry
-    fences kernel outputs with ``block_until_ready``, so a span's duration
-    is honest host+device time and splits into ``dispatch_s`` (host until
-    the call returned) and ``sync_s`` (device wait); achieved FLOP/s over
-    that wall time is compared against the arithmetic-intensity-capped roof
-    ``min(PEAK_FLOPS, ai * HBM_BW)``."""
-    rows = []
-    for ev in events:
-        args = ev.get("args", {})
-        if ev.get("ph") != "X" or ev.get("cat") != "gcda":
-            continue
-        if "flops" not in args or not ev.get("dur"):
-            continue
-        seconds = ev["dur"] / 1e6
-        flops = float(args["flops"])
-        nbytes = float(args.get("bytes", 0.0))
-        ai = flops / nbytes if nbytes else 0.0
-        roof = min(PEAK_FLOPS, ai * HBM_BW) if ai else PEAK_FLOPS
-        achieved = flops / seconds
-        rows.append({
-            "table": "kernel_roofline", "op": ev["name"],
-            "seconds": seconds,
-            "dispatch_s": args.get("dispatch_s", 0.0),
-            "sync_s": args.get("sync_s", 0.0),
-            "flops": flops, "bytes": nbytes,
-            "arithmetic_intensity": ai,
-            "achieved_gflops": achieved / 1e9,
-            "roof_gflops": roof / 1e9,
-            "roofline_frac": achieved / roof if roof else 0.0,
-        })
-    return rows
-
-
 def what_would_help(row: dict) -> str:
     if row["dominant"] == "collective":
         return "cut collective bytes: bf16 collectives, reduce-scatter " \

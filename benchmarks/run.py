@@ -66,8 +66,8 @@ def _index_suite(sf: int, fast: bool) -> list[dict]:
 def _trace_suite(sf: int, fast: bool) -> list[dict]:
     """Telemetry: traced GCDIA reuse ladder exported as Chrome trace-event
     JSON (schema-validated; experiments/trace_gcdia.json — open it in
-    Perfetto), kernel roofline attribution from the fenced GCDA spans, and
-    the disabled-telemetry overhead guard vs the pre-telemetry executor."""
+    Perfetto) and the disabled-telemetry overhead guard vs the
+    pre-telemetry executor."""
     from . import trace_bench
     rows = trace_bench.run_suite(sf=sf, fast=fast)
     trace_bench.print_rows(rows)
@@ -78,8 +78,7 @@ def _kernels_suite(sf: int, fast: bool) -> list[dict]:
     """Traversal kernel family: single-query latency ladder (host matcher vs
     per-hop jit vs whole-chain program) over start selectivity, batched
     point-lookup throughput (launch amortization across >=64 concurrent
-    queries), and achieved-vs-roof bandwidth of the DeviceMatchPattern
-    kernel spans from the engine's fenced trace export."""
+    queries)."""
     from . import traversal_bench
     rows = traversal_bench.run_suite(sf=sf, fast=fast)
     traversal_bench.print_rows(rows)
@@ -174,7 +173,7 @@ def main() -> None:
                          "trace: telemetry smoke — traced GCDIA with "
                          "Chrome-trace export + disabled-overhead guard; "
                          "kernels: traversal kernel family — latency "
-                         "ladder, batched point lookups, kernel roofline; "
+                         "ladder, batched point lookups; "
                          "shard: morsel-parallel execution — single-stream "
                          "vs 4-shard latency, born-sharded GCDA handoff, "
                          "small-input serial gate")

@@ -1,19 +1,14 @@
 """Trace suite: telemetry smoke + disabled-path overhead guard.
 
-Three deliverables (ISSUE 6 acceptance):
-
 1. Run a GCDIA reuse ladder (cold A3 multiply, then the warm A2 similarity
    that shares its GCDI sub-plan) with tracing on; export the Chrome
    trace-event JSON to ``experiments/trace_gcdia.json`` and validate it —
    the spans must cover every executed operator of the DAG *including*
    inter-buffer-hit pseudo-spans.
-2. Kernel roofline attribution rows from the fenced GCDA spans
-   (``roofline.from_trace``): dispatch vs device-sync time, achieved
-   GFLOP/s against the arithmetic-intensity-capped roof.
-3. Measure the disabled-telemetry executor against a frozen replica of the
+2. Measure the disabled-telemetry executor against a frozen replica of the
    pre-telemetry ``physical.execute`` on the same DAG. The replica is the
    honest baseline: it is byte-for-byte the old executor body, so the
-   comparison isolates exactly what this PR added to the hot path (see
+   comparison isolates exactly what tracing added to the hot path (see
    ``measure_overhead`` for why walk time — wall minus internally-timed
    ``node.run`` — is the only estimator that resolves it under jax
    dispatch noise). Must stay < 2% of end-to-end query time
@@ -33,8 +28,6 @@ from repro.core import GredoEngine, validate_chrome_trace
 from repro.core import physical, telemetry
 from repro.core.interbuffer import fingerprint, value_nbytes
 from repro.data import m2bench
-
-from . import roofline
 
 
 # ---------------------------------------------------------------------------
@@ -184,7 +177,6 @@ def traced_gcdia(sf: int = 1,
             "qerror_flags": len(prof.qerrors),
             "trace_file": out_path,
         })
-    rows += roofline.from_trace(doc["traceEvents"])
     return rows
 
 
@@ -200,11 +192,6 @@ def print_rows(rows: list[dict]) -> None:
             print(f"trace_{r['step']}_sf{r['sf']},{r['seconds']*1e6:.1f},"
                   f"spans={r['spans']};cache_spans={r['cache_pseudo_spans']};"
                   f"qerror_flags={r['qerror_flags']}")
-        elif r["table"] == "kernel_roofline":
-            print(f"trace_kernel_{r['op']},{r['seconds']*1e6:.1f},"
-                  f"gflops={r['achieved_gflops']:.2f};"
-                  f"roof_frac={r['roofline_frac']:.4f};"
-                  f"sync_us={r['sync_s']*1e6:.1f}")
         elif r["table"] == "trace_overhead":
             print(f"trace_disabled_overhead,{r['disabled_s']*1e6:.1f},"
                   f"baseline_us={r['baseline_s']*1e6:.1f};"

@@ -10,9 +10,7 @@ sequential dispatch sequences. Times are host wall-clock on whatever
 backend runs the benchmark.
 
 Tables: traversal_ladder (single-query latency vs start selectivity),
-traversal_batched (point-lookup throughput), traversal_roofline
-(achieved-vs-roof bandwidth of the DeviceMatchPattern spans, from the
-engine's fenced trace export).
+traversal_batched (point-lookup throughput).
 """
 from __future__ import annotations
 
@@ -20,14 +18,11 @@ import time
 
 import numpy as np
 
-from repro.core import GredoEngine, optimizer, physical
 from repro.core.pattern import match, plan_pattern
 from repro.core.pattern_jit import device_match, get_matcher
-from repro.core.schema import Predicate, Query, chain_pattern
+from repro.core.schema import Predicate, chain_pattern
 from repro.core.storage import Database, Graph, Table
 from repro.kernels.traversal import ops as kops
-
-from . import roofline
 
 GRAPH = "Chain"
 SEL_LADDER = (1e-4, 1e-3, 1e-2, 1e-1)
@@ -149,39 +144,11 @@ def batched_throughput(sf: int = 1, repeat: int = 3,
     return rows
 
 
-def roofline_rows(sf: int = 1) -> list[dict]:
-    """Run a selective 2-hop query through the engine (the optimizer lowers
-    it to DeviceMatchPattern) and attribute the fenced kernel spans against
-    the TPU roofline from the Chrome trace export."""
-    db = make_db(sf=sf)
-    eng = GredoEngine(db, telemetry=True)
-    q = Query(select=("a.vid", "c.vid"), froms=(), match=_pattern(),
-              where=(Predicate("a.grp", "<", 100),
-                     Predicate("e0.w", "<=", W_CUT),
-                     Predicate("e1.w", "<=", W_CUT)))
-    eng.query(q)
-    dag = physical.explain(eng.last_dag)
-    if "DeviceMatchPattern" not in dag:
-        raise AssertionError("optimizer did not pick the device access path:"
-                             f"\n{dag}")
-    events = eng.telemetry.collector.to_chrome()["traceEvents"]
-    rows = []
-    for r in roofline.from_trace(events):
-        if r["op"] != "DeviceMatchPattern":
-            continue
-        r = dict(r, table="traversal_roofline", sf=sf)
-        rows.append(r)
-    if not rows:
-        raise AssertionError("no DeviceMatchPattern roofline rows in trace")
-    return rows
-
-
 def run_suite(sf: int = 1, fast: bool = False) -> list[dict]:
     repeat = 2 if fast else 5
     rows = latency_ladder(sf=sf, repeat=repeat)
     rows += batched_throughput(sf=sf, repeat=max(repeat - 1, 1),
                                batches=(64,) if fast else (64, 256))
-    rows += roofline_rows(sf=sf)
     return rows
 
 
@@ -199,8 +166,3 @@ def print_rows(rows: list[dict]) -> None:
                   f"qps={r['batched_qps']:.0f};"
                   f"vs_seq_jit={r['speedup_vs_seq_jit']:.2f};"
                   f"vs_seq_fused={r['speedup_vs_seq_fused']:.2f}")
-        elif r["table"] == "traversal_roofline":
-            print(f"traversal_kernel_{r['op']},{r['seconds']*1e6:.1f},"
-                  f"gflops={r['achieved_gflops']:.2f};"
-                  f"roof_frac={r['roofline_frac']:.5f};"
-                  f"bytes={r['bytes']}")

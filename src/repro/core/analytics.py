@@ -1,6 +1,6 @@
 """Parallel GCDA operators (paper §5.4, Table 3) + matrix generation.
 
-* Matrix generation: ``rel2matrix`` (local access — columnar reads, no
+* Matrix generation: ``rel2matrix_host`` (local access — columnar reads, no
   tuple-at-a-time scan) and ``random_access_matrix`` (aggregate multi-valued
   attributes from qualifying records into multi-hot / count features).
 * Analytical operators: MULTIPLY / SIMILARITY / REGRESSION, block-tiled
@@ -31,8 +31,8 @@ from .storage import DictColumn, RaggedColumn, Table
 # ---------------------------------------------------------------------------
 
 
-def rel2matrix(table: Table, columns: Sequence[str]) -> jax.Array:
-    """REL2MATRIX: local access — assemble numeric columns into an (n, k)
+def rel2matrix_host(table: Table, columns: Sequence[str]) -> np.ndarray:
+    """REL2MATRIX's host assembly: numeric columns into an (n, k) float32
     matrix straight from columnar storage (bypasses row iteration)."""
     cols = []
     for c in columns:
@@ -41,7 +41,7 @@ def rel2matrix(table: Table, columns: Sequence[str]) -> jax.Array:
             cols.append(col.codes.astype(np.float32))
         else:
             cols.append(np.asarray(col, dtype=np.float32))
-    return jnp.asarray(np.stack(cols, axis=1))
+    return np.stack(cols, axis=1)
 
 
 def rel2matrix_sharded(table: Table, columns: Sequence[str], k: int
@@ -50,8 +50,8 @@ def rel2matrix_sharded(table: Table, columns: Sequence[str], k: int
     and staged to the device independently, then the blocks are concatenated
     *device-side* — the downstream GCDA kernels (MatMul / Similarity /
     Regression) consume the result without a host gather. Values are
-    bit-identical to :func:`rel2matrix` (same per-element float32 cast, same
-    row order). With more than one device the blocks land on a 1-D ``data``
+    bit-identical to :func:`rel2matrix_host` (same per-element float32
+    cast, same row order). With more than one device the blocks land on a 1-D ``data``
     mesh via :class:`NamedSharding`; on a single device the block layout
     still avoids materializing the full host-side matrix at once.
 
@@ -94,12 +94,13 @@ def rel2matrix_sharded(table: Table, columns: Sequence[str], k: int
     return mat, spec
 
 
-def random_access_matrix(table: Table, group_col: str, value_col: str,
-                         n_features: int, mode: str = "multi_hot"
-                         ) -> tuple[jax.Array, np.ndarray]:
-    """Random access — aggregate (multi-valued) attributes of qualifying
-    records into per-group feature rows. Returns (matrix, group_ids): row i
-    holds the multi-hot / count vector of ``value_col`` over group i."""
+def random_access_matrix_host(table: Table, group_col: str, value_col: str,
+                              n_features: int, mode: str = "multi_hot"
+                              ) -> tuple[np.ndarray, np.ndarray]:
+    """Random access's host assembly — aggregate (multi-valued) attributes
+    of qualifying records into per-group feature rows. Returns (matrix,
+    group_ids): row i holds the multi-hot / count vector of ``value_col``
+    over group i."""
     groups = np.asarray(table.col(group_col))
     vcol = table.col(value_col)
     if isinstance(vcol, RaggedColumn):
@@ -114,36 +115,22 @@ def random_access_matrix(table: Table, group_col: str, value_col: str,
     np.add.at(mat, (row_idx[ok], vals[ok].astype(np.int64)), 1.0)
     if mode == "multi_hot":
         mat = np.minimum(mat, 1.0)
+    return mat, uniq
+
+
+def random_access_matrix(table: Table, group_col: str, value_col: str,
+                         n_features: int, mode: str = "multi_hot"
+                         ) -> tuple[jax.Array, np.ndarray]:
+    """Random access: :func:`random_access_matrix_host` with the matrix on
+    the device."""
+    mat, uniq = random_access_matrix_host(table, group_col, value_col,
+                                          n_features, mode)
     return jnp.asarray(mat), uniq
 
 
 # ---------------------------------------------------------------------------
 # Analytical operators (A in Eq. 5): block-parallel Pallas execution
 # ---------------------------------------------------------------------------
-
-
-def flops_estimate(op: str, shapes: Sequence[Sequence[int]],
-                   iters: int = 1) -> float:
-    """Analytic floating-point work of one analytical-operator execution,
-    from its input shapes — the kernel-span payload telemetry attaches and
-    ``benchmarks/roofline.py`` compares against the hardware roofline.
-    ``op`` is a physical-operator kind ("MatMul" / "Similarity" /
-    "Regression"); unknown ops and degenerate shapes cost 0."""
-    shapes = [tuple(int(d) for d in s) for s in shapes]
-    if not shapes or len(shapes[0]) != 2:
-        return 0.0
-    m, k = shapes[0]
-    if op == "MatMul":
-        n = shapes[1][1] if len(shapes) > 1 and len(shapes[1]) == 2 else m
-        return 2.0 * m * k * n
-    if op == "Similarity":
-        # fused cosine: the dot products plus both norm reductions
-        n = shapes[1][0] if len(shapes) > 1 and len(shapes[1]) == 2 else m
-        return 3.0 * m * k * n
-    if op == "Regression":
-        # per iteration: forward matvec + gradient matvec over (m, k)
-        return 4.0 * m * k * max(iters, 1)
-    return 0.0
 
 
 def multiply(x: jax.Array, y: jax.Array, *, mesh: Optional[Mesh] = None,

@@ -360,7 +360,16 @@ class GredoEngine:
     def query(self, q: Query) -> Table:
         traversal.COUNTERS.reset()
         trace, ib0 = self._begin_query(f"query[{','.join(q.source_names())}]")
+        try:
+            return self._query(q, trace, ib0)
+        finally:
+            if trace is not None:
+                trace.close()       # a request that raised leaves none open
+
+    def _query(self, q: Query, trace, ib0: dict) -> Table:
         t0 = time.perf_counter()
+        if trace is not None:
+            trace.phase("plan")
         p = self.plan(q)
         naive = physical.build_gcdi(self.db, p, mode=self.mode)
         dag, report = self._lower(naive)
@@ -370,7 +379,11 @@ class GredoEngine:
         ctx = physical.ExecContext(self.db, trace=trace,
                                    fence_device=self._fence_device(),
                                    shard=shard_rt)
+        if trace is not None:
+            trace.phase(None)
         result = physical.execute(dag, ctx)
+        if trace is not None:
+            trace.phase("finish")
         notes = list(p.notes)
         if self.mode == "single" and q.match is not None:
             notes.insert(0, "single-engine: match via edge-table equi-joins")
@@ -477,10 +490,11 @@ class GredoEngine:
     def _fence_device(self) -> bool:
         return self.telemetry is not None and self.telemetry.fence_device
 
-    def _begin_query(self, label: str):
+    def _begin_query(self, label: str, kind: str = "query"):
         """Open the per-query observability window: an inter-buffer counter
         snapshot (always — 6 ints), the flight recorder's pre-query marks,
-        and with telemetry on, a registry snapshot plus a fresh trace."""
+        and with telemetry on, a registry snapshot plus a fresh trace whose
+        root span is named ``kind``."""
         ib0 = self.interbuffer.metrics()
         self._last_label = label
         if self.observer is not None:
@@ -488,9 +502,10 @@ class GredoEngine:
         tel = self.telemetry
         if tel is None:
             return None, ib0
+        trace = tel.collector.start_query(label, kind)
         self._pre_snapshot = tel.registry.snapshot()
         tel.qerror.start_plan()
-        return tel.collector.start_query(label), ib0
+        return trace, ib0
 
     def _finish_query(self, trace, ctx: physical.ExecContext,
                       ib0: dict, kind: str = "query") -> None:
@@ -505,11 +520,6 @@ class GredoEngine:
                 self.observer.observe(self, kind=kind)
             return
         seconds = self.last_stats.seconds
-        if trace is not None:
-            trace.close(seconds=seconds, nodes_run=ctx.nodes_run,
-                        nodes_reused=ctx.nodes_reused)
-            tel.collector.trim()    # re-check the span bound now that this
-                                    # query's spans are all recorded
         reg = tel.registry
         reg.counter("engine.queries").inc()
         reg.histogram("engine.query_seconds").observe(seconds)
@@ -539,6 +549,13 @@ class GredoEngine:
             self._pre_snapshot, reg.snapshot())
         if self.observer is not None:
             self.observer.observe(self, kind=kind)
+        if trace is not None:
+            # the root (and the ``finish`` phase) close last, so this
+            # bookkeeping is inside the query's span
+            trace.close(seconds=seconds, nodes_run=ctx.nodes_run,
+                        nodes_reused=ctx.nodes_reused)
+            tel.collector.trim()    # re-check the span bound now that this
+                                    # query's spans are all recorded
 
     # ------------------------------------------------------------------ GCDA
     def analyze(self, task: GCDIATask, *, use_kernel: bool | None = None,
@@ -549,8 +566,19 @@ class GredoEngine:
         signature; signatures embed source write epochs, so reuse survives
         exactly until a source collection mutates."""
         traversal.COUNTERS.reset()
-        trace, ib0 = self._begin_query(f"gcdia:{task.analytics.op}")
+        trace, ib0 = self._begin_query(f"gcdia:{task.analytics.op}",
+                                       kind="analyze")
+        try:
+            return self._analyze(task, trace, ib0, use_kernel, iters)
+        finally:
+            if trace is not None:
+                trace.close()       # a request that raised leaves none open
+
+    def _analyze(self, task: GCDIATask, trace, ib0: dict,
+                 use_kernel: bool | None, iters: int):
         t0 = time.perf_counter()
+        if trace is not None:
+            trace.phase("plan")
         p = self.plan(task.integration)
         naive = physical.build_gcdia(self.db, p, task, mode=self.mode,
                                      use_kernel=use_kernel, iters=iters)
@@ -563,7 +591,11 @@ class GredoEngine:
                                    ests=ests, trace=trace,
                                    fence_device=self._fence_device(),
                                    shard=shard_rt)
+        if trace is not None:
+            trace.phase(None)
         out = physical.execute(dag, ctx)
+        if trace is not None:
+            trace.phase("finish")
         self.last_dag = dag
         self.last_naive_dag = naive
         self.last_report = report

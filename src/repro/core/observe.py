@@ -217,12 +217,18 @@ _MAX_RECORD_SPANS = 512
 
 def _span_tree(trace) -> list:
     """Serialize a QueryTrace's spans (id/parent/name/dur/detail), bounded
-    so one pathological plan can't bloat every dump."""
+    so one pathological plan can't bloat every dump. The record is taken
+    inside the query's ``finish`` phase: a span still open (the root, the
+    phase) reads its duration so far. A ``cat == "phase"`` span is part of
+    its parent's own time."""
     if trace is None:
         return []
     spans = list(getattr(trace, "spans", ()))[:_MAX_RECORD_SPANS]
+    open_ = set(trace.open_spans())
+    now = time.perf_counter() - trace.t0
     return [{"id": s.id, "parent": s.parent, "name": s.name, "cat": s.cat,
-             "dur": round(s.dur, 9), "detail": s.detail,
+             "dur": round(now - s.ts if s.id in open_ else s.dur, 9),
+             "detail": s.detail,
              "args": _finite({k: v for k, v in s.args.items()
                               if isinstance(v, (int, float, str, bool))})}
             for s in spans]

@@ -354,47 +354,31 @@ def _estimate_capacity(g: Graph, prep: dict) -> int:
     return _round_capacity(int(2.0 * peak))
 
 
-def _kernel_span_args(hops: int, capacity: int, n_vertices: int,
-                      n_edges: int, prep: dict, launches: int) -> dict:
-    """Analytic flops/bytes of the device traversal — the span payload
-    ``roofline.from_trace`` reads (the operator is a DAG leaf, so the
-    generic shape-derived model in ``telemetry.kernel_args`` has nothing to
-    work from). Memory model: per hop, three int32 outputs plus per-slot
-    gather traffic over the padded capacity (the device moves padded
-    arrays regardless of validity), plus the predicate tables actually
-    read — edge tables scaled by the zone-survivor fraction."""
-    per_slot = 3 * 4 + (4 + 4 + 8 + 2 + 1)    # outputs + gathers
-    tbl_bytes = 0.0
-    for mem in prep["members"]:
-        if mem is not None:
-            tbl_bytes += n_vertices
-    for ep, ca in zip(prep["edge_preds"], prep["chunk_alives"]):
-        if ep is None:
-            continue
-        frac = (float(ca.sum()) / max(len(ca), 1)) if ca is not None else 1.0
-        tbl_bytes += frac * n_edges + (0 if ca is None else len(ca))
-    flops = float(hops) * capacity * 12.0 * launches
-    nbytes = (float(hops) * capacity * per_slot * launches + tbl_bytes)
-    return {"flops": flops, "bytes": int(nbytes), "hops": hops,
-            "capacity": capacity,
-            "zone_chunks_alive": kernel_ops.COUNTERS.chunks_alive,
-            "zone_chunks_total": kernel_ops.COUNTERS.chunks_total}
-
-
 def device_match(g: Graph, pplan, *, flavor: str = "chain",
                  initial_capacity: Optional[int] = None,
                  max_capacity: int = 1 << 24,
-                 use_kernel: bool = False):
+                 use_kernel: bool = False, trace=None):
     """Execute a chain PatternPlan on the device path and build the same
     graph-relation Table as ``pattern.match`` (vertex columns hold vids,
     edge columns hold tids; deferred predicates applied). Returns
-    (rel, kernel_args) — the second element is the telemetry span payload.
+    (rel, counts): the run's ``flavor``, ``hops``, final ``capacity`` and
+    chain ``launches``, for the operator's span.
     ``flavor``: "chain" (one program per chain, zone-filtered tables) or
     "jit" (per-hop ``DevicePatternMatcher``). ``use_kernel=True`` runs the
     chain's hops through the Pallas kernel (tests only; see
-    ``repro.kernels.traversal.ops``). Raises ``StaleSnapshotError`` on
-    pending deltas; callers degrade to the host matcher."""
+    ``repro.kernels.traversal.ops``). With a ``trace`` (the operator's
+    ``QueryTrace``), the chain flavor records its phases: ``lower`` (the
+    snapshot refresh, the plan lowered to tables, the capacity), ``stage``
+    (tables and frontier to the device), ``launch`` (the program through
+    its overflow sync; an overflow repeats ``stage`` and ``launch``) and
+    ``readback`` (the trimmed columns to the host and the relation).
+    Raises ``StaleSnapshotError`` on pending deltas; callers degrade to the
+    host matcher."""
+    if flavor != "chain":
+        trace = None
     COUNTERS.matches += 1
+    if trace is not None:
+        trace.phase("lower")
     matcher = get_matcher(g)
     matcher.refresh()
     prep = prepare_chain(g, pplan, zone=(flavor == "chain"))
@@ -417,12 +401,18 @@ def device_match(g: Graph, pplan, *, flavor: str = "chain",
         cap = initial_capacity or _estimate_capacity(g, prep)
         cap = max(cap, _round_capacity(len(start)))
         while True:
-            vcols, ecols, ok = kernel_ops.traverse_chain(
+            if trace is not None:
+                trace.phase("stage")
+            staged = kernel_ops.stage_chain(
                 row_ptr, col_idx, edge_id, g.n_vertices, g.edges.nrows,
                 start, prep["members"], prep["edge_preds"],
-                prep["chunk_alives"], capacity=cap, chunk=prep["chunk"],
+                prep["chunk_alives"], capacity=cap, chunk=prep["chunk"])
+            if trace is not None:
+                trace.phase("launch")
+            launched = kernel_ops.launch_chain(
+                staged, capacity=cap, chunk=prep["chunk"],
                 use_kernel=use_kernel)
-            if ok:
+            if launched is not None:
                 break
             if cap >= max_capacity:
                 raise RuntimeError(f"pattern frontier exceeded max capacity "
@@ -431,6 +421,9 @@ def device_match(g: Graph, pplan, *, flavor: str = "chain",
             launches += 1
             COUNTERS.retries += 1
             COUNTERS.bump_retry(cap)
+        if trace is not None:
+            trace.phase("readback")
+        vcols, ecols = kernel_ops.read_chain(launched)
 
     if prep["reverse"]:
         vcols = vcols[::-1]
@@ -442,7 +435,7 @@ def device_match(g: Graph, pplan, *, flavor: str = "chain",
         cols[evar] = col
     rel = Table(f"match:{pattern.graph}", cols)
     rel = pattern_mod.apply_deferred(g, pattern, rel, pplan.deferred)
-    kargs = _kernel_span_args(hops, cap, g.n_vertices, g.edges.nrows, prep,
-                              launches)
-    kargs["flavor"] = flavor
-    return rel, kargs
+    if trace is not None:
+        trace.phase(None)
+    return rel, {"flavor": flavor, "hops": hops, "capacity": cap,
+                 "launches": launches}

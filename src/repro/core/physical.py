@@ -413,9 +413,8 @@ class DeviceMatchPattern(PhysicalOp):
         self.pplan = pplan
         self.access = access
         self.capacity = capacity
-        # per-execution analytic flops/bytes; merged into the telemetry span
-        # (this is a DAG leaf — the generic shape-derived kernel_args model
-        # has no inputs to derive from)
+        # the counts of the last run (flavor, hops, capacity, launches);
+        # merged into the telemetry span
         self.last_kernel_args: Optional[dict] = None
 
     def params(self):
@@ -432,7 +431,8 @@ class DeviceMatchPattern(PhysicalOp):
             return pattern_mod.match(g, self.pplan)
         flavor = "jit" if self.access == "device-jit" else "chain"
         rel, kargs = pattern_jit.device_match(
-            g, self.pplan, flavor=flavor, initial_capacity=self.capacity)
+            g, self.pplan, flavor=flavor, initial_capacity=self.capacity,
+            trace=ctx.trace)
         self.last_kernel_args = kargs
         return rel
 
@@ -689,6 +689,24 @@ class Project(PhysicalOp):
 # ---------------------------------------------------------------------------
 
 
+def _to_device(ctx, build):
+    """A matrix builder's run: the host assembly ``build()`` (phase
+    ``build``), then its transfer to the device (phase ``transfer``, which
+    with ``fence_device`` ends once the matrix is on the device)."""
+    import jax.numpy as jnp
+    trace = ctx.trace
+    if trace is None:
+        return jnp.asarray(build())
+    trace.phase("build")
+    host = build()
+    trace.phase("transfer")
+    out = jnp.asarray(host)
+    if ctx.fence_device:
+        telemetry.fence(out)
+    trace.phase(None)
+    return out
+
+
 class Rel2Matrix(PhysicalOp):
     """REL2MATRIX local access: columnar GCDI columns -> (n, k) device matrix."""
     kind = "Rel2Matrix"
@@ -702,7 +720,8 @@ class Rel2Matrix(PhysicalOp):
         return (self.columns,)
 
     def run(self, ctx, rel: Table):
-        return analytics.rel2matrix(rel, self.columns)
+        return _to_device(ctx, lambda: analytics.rel2matrix_host(
+            rel, self.columns))
 
     def describe(self):
         return f"Rel2Matrix[{', '.join(self.columns)}]"
@@ -725,9 +744,8 @@ class RandomAccessMatrix(PhysicalOp):
         return (self.group_col, self.value_col, self.n_features)
 
     def run(self, ctx, rel: Table):
-        m, _ = analytics.random_access_matrix(
-            rel, self.group_col, self.value_col, self.n_features)
-        return m
+        return _to_device(ctx, lambda: analytics.random_access_matrix_host(
+            rel, self.group_col, self.value_col, self.n_features)[0])
 
     def describe(self):
         return (f"RandomAccessMatrix[{self.group_col} x {self.value_col} "
@@ -1164,12 +1182,11 @@ def execute(node: PhysicalOp, ctx: ExecContext):
                 sync = telemetry.fence(out)
                 args["sync_s"] = sync
                 node.stats.seconds += sync  # device wait belongs to the op
-            args.update(telemetry.kernel_args(node.kind, tuple(inputs), out,
-                                              iters=getattr(node, "iters", 1)))
             extra = getattr(node, "last_kernel_args", None)
             if extra:
-                # leaf kernels (DeviceMatchPattern) report their own
-                # flops/bytes — the shape-derived model above sees no inputs
+                # what the operator reports of its own run: the device
+                # traversal's flavor, hops, capacity and launches; the
+                # born-sharded matrix's shard spec
                 args.update(extra)
         if node.stats.rows is not None:
             args["rows"] = node.stats.rows

@@ -15,22 +15,30 @@ them behind three primitives, all off-by-default and designed so the
 * **Spans** — every physical-operator execution emits a :class:`Span`
   (op kind, wall seconds, rows/bytes, est vs. actual rows, access-path and
   cache provenance) into a bounded per-engine :class:`TraceCollector`.
-  Traces export as Chrome trace-event JSON (:meth:`TraceCollector.to_chrome`,
-  loadable in Perfetto / ``chrome://tracing``) and as an ``EXPLAIN
-  ANALYZE``-style annotated tree (:meth:`QueryTrace.render`).
+  Under the query root the engine adds two phase spans, ``plan`` (before
+  the executor) and ``finish`` (its own bookkeeping), and the operators
+  that set the device's pace add phase spans of their own
+  (``DeviceMatchPattern``: ``lower``/``stage``/``launch``/``readback``;
+  the matrix builders: ``build``/``transfer``). Phase spans (category
+  ``"phase"``) are part of their parent's own time, not operators.
+  Each span is also a ``jax.profiler.TraceAnnotation`` named
+  ``gredo:<name>``, so a profiler trace puts the span tree on the same
+  clock as the device's operations. Traces export as Chrome trace-event
+  JSON (:meth:`TraceCollector.to_chrome`, loadable in Perfetto /
+  ``chrome://tracing``) and as an ``EXPLAIN ANALYZE``-style annotated tree
+  (:meth:`QueryTrace.render`).
 * **Q-error monitor** — per-operator ``max(est/actual, actual/est)`` row
   ratios land in a bounded misestimate log; operators above a configurable
   threshold are flagged per plan (:class:`QErrorMonitor`) — the feedback
   hook the optimizer's stats revalidation will consume.
 
-GCDA kernel spans carry ``dispatch_s`` (host time until the call returns)
-and ``sync_s`` (``block_until_ready`` wait), so jit/device time is
-attributed separately from host time; ``benchmarks/roofline.py`` consumes
-these via its ``from_trace`` helper.
+GCDA operator spans carry ``dispatch_s`` (host time until the call
+returns) and, with ``fence_device``, ``sync_s`` (``block_until_ready``
+wait), so device time is attributed to the operator that waits for it.
 
 Everything here is dependency-free within the engine (numpy + stdlib; jax
-only through duck-typed ``block_until_ready``), so every core module may
-import it without cycles.
+only through duck-typed ``block_until_ready`` and a lazy import of
+``jax.profiler``), so every core module may import it without cycles.
 """
 from __future__ import annotations
 
@@ -333,17 +341,38 @@ def default_registry() -> Registry:
 # ---------------------------------------------------------------------------
 
 
+# Profiler annotations of the engine's spans are named ``gredo:<span name>``.
+ANNOTATION_PREFIX = "gredo:"
+# Category of the phase spans an operator (or the engine, under the query
+# root) opens inside its own time; they are not operators.
+PHASE = "phase"
+
+_TraceAnnotation = None
+
+
+def _annotate(name: str):
+    """Enter a ``jax.profiler.TraceAnnotation`` for a span; jax is imported
+    on first use. With no profiler recording it costs about a microsecond."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    ann = _TraceAnnotation(ANNOTATION_PREFIX + name)
+    ann.__enter__()
+    return ann
+
+
 @dataclasses.dataclass
 class Span:
-    """One operator execution (or cache pseudo-event) in a query trace.
-    ``ts``/``dur`` are seconds relative to the owning trace's origin; spans
-    of a query nest strictly (a parent opens before and closes after all of
-    its children)."""
+    """One operator execution (or cache pseudo-event, or phase) in a query
+    trace. ``ts``/``dur`` are seconds relative to the owning trace's origin;
+    spans of a query nest strictly (a parent opens before and closes after
+    all of its children)."""
 
     id: int
     parent: int             # -1 for the query root
     name: str               # operator kind ("MatchPattern", "EquiJoin", ...)
-    cat: str                # "gcdi" | "gcda" | "cache" | "query"
+    cat: str                # "gcdi" | "gcda" | "cache" | "phase" | "query"
     ts: float
     dur: float = 0.0
     detail: str = ""        # PhysicalOp.describe()
@@ -354,18 +383,25 @@ class QueryTrace:
     """The span tree of one query/analyze execution. ``begin``/``end`` keep
     an explicit open-span stack, matching the executor's recursion; an
     ``instant`` span records cache hits (inter-buffer / memo) as zero-ish
-    duration pseudo-spans so the trace covers every DAG node touched."""
+    duration pseudo-spans so the trace covers every DAG node touched.
+    ``phase`` opens the phases of the innermost open span one after the
+    other. Every open span holds a profiler annotation of the same name,
+    entered and exited on the thread that runs the query, so the
+    annotations nest like the spans."""
 
-    def __init__(self, label: str, origin: Optional[float] = None):
+    def __init__(self, label: str, origin: Optional[float] = None,
+                 kind: str = "query"):
         self.label = label
         self.t0 = time.perf_counter() if origin is None else origin
         self.spans: list[Span] = []
         self._stack: list[int] = []
+        self._ann: dict[int, Any] = {}
         self._lock = threading.Lock()
-        root = Span(id=0, parent=-1, name="query", cat="query",
+        root = Span(id=0, parent=-1, name=kind, cat="query",
                     ts=0.0, detail=label)
         self.spans.append(root)
         self._stack.append(0)
+        self._ann[0] = _annotate(kind)
 
     # -- recording --
     def begin(self, name: str, cat: str = "gcdi", detail: str = "") -> int:
@@ -375,18 +411,34 @@ class QueryTrace:
                                    cat=cat, ts=time.perf_counter() - self.t0,
                                    detail=detail))
             self._stack.append(sid)
+            self._ann[sid] = _annotate(name)
             return sid
 
     def end(self, sid: int, **args) -> None:
+        """Close ``sid`` and every span still open inside it; a span that
+        is no longer open only takes the ``args``."""
         with self._lock:
-            s = self.spans[sid]
-            s.dur = (time.perf_counter() - self.t0) - s.ts
             if args:
-                s.args.update(args)
-            while self._stack and self._stack[-1] != sid:
-                self._stack.pop()       # tolerate unbalanced ends
-            if self._stack:
-                self._stack.pop()
+                self.spans[sid].args.update(args)
+            if sid not in self._stack:
+                return
+            now = time.perf_counter() - self.t0
+            while True:
+                top = self._stack.pop()
+                s = self.spans[top]
+                s.dur = now - s.ts
+                self._ann.pop(top).__exit__(None, None, None)
+                if top == sid:
+                    return
+
+    def phase(self, name: Optional[str]) -> None:
+        """End the open phase of the innermost open span, if there is one,
+        and open phase ``name`` in its place (``None``: open none)."""
+        top = self._stack[-1]
+        if self.spans[top].cat == PHASE:
+            self.end(top)
+        if name is not None:
+            self.begin(name, cat=PHASE)
 
     def instant(self, name: str, detail: str = "", **args) -> int:
         sid = self.begin(name, cat="cache", detail=detail)
@@ -394,10 +446,14 @@ class QueryTrace:
         return sid
 
     def close(self, **args) -> None:
-        """Close the query root (and anything left open)."""
-        for sid in reversed(self._stack[1:]):
-            self.end(sid)
-        self.end(0, **args)
+        """Close the query root and anything left open; once closed, a
+        second call changes nothing."""
+        if self._stack:
+            self.end(0, **args)
+
+    def open_spans(self) -> list[int]:
+        """Ids of the spans still open, outermost first."""
+        return list(self._stack)
 
     # -- views --
     def children_of(self, sid: int) -> list[Span]:
@@ -405,9 +461,11 @@ class QueryTrace:
 
     def shape(self) -> list:
         """Nested ``(name, [children...])`` of the operator spans — directly
-        comparable to the physical DAG's structure in tests."""
+        comparable to the physical DAG's structure in tests. Phase spans
+        are part of their parent and do not appear."""
         def rec(sid: int):
-            return [(s.name, rec(s.id)) for s in self.children_of(sid)]
+            return [(s.name, rec(s.id)) for s in self.children_of(sid)
+                    if s.cat != PHASE]
         return rec(0)
 
     def total_seconds(self) -> float:
@@ -421,7 +479,10 @@ class QueryTrace:
         lines: list[str] = []
 
         def self_seconds(s: Span) -> float:
-            return s.dur - sum(c.dur for c in self.children_of(s.id))
+            """Wall seconds less those of child operators; the span's own
+            phases are part of it."""
+            return s.dur - sum(c.dur for c in self.children_of(s.id)
+                               if c.cat != PHASE)
 
         def rec(sid: int, depth: int):
             for s in self.children_of(sid):
@@ -431,7 +492,9 @@ class QueryTrace:
                     if k in s.args:
                         v = s.args[k]
                         bits.append(f"{k}={v:.3g}" if isinstance(v, float) else f"{k}={v}")
-                lines.append("  " * depth + (s.detail or s.name)
+                name = (f"[{s.name}]" if s.cat == PHASE
+                        else s.detail or s.name)
+                lines.append("  " * depth + name
                              + "  (" + ", ".join(bits) + ")")
                 rec(s.id, depth + 1)
 
@@ -459,8 +522,8 @@ class TraceCollector:
         self.dropped_spans = 0
         self._lock = threading.Lock()
 
-    def start_query(self, label: str) -> QueryTrace:
-        qt = QueryTrace(label)
+    def start_query(self, label: str, kind: str = "query") -> QueryTrace:
+        qt = QueryTrace(label, kind=kind)
         with self._lock:
             self.traces.append(qt)
             self._trim_locked()
@@ -625,7 +688,7 @@ class QErrorMonitor:
 
 
 # ---------------------------------------------------------------------------
-# GCDA kernel attribution helpers
+# GCDA operator attribution
 # ---------------------------------------------------------------------------
 
 GCDA_KINDS = ("Rel2Matrix", "RandomAccessMatrix", "MatMul", "Similarity",
@@ -640,33 +703,6 @@ def fence(value) -> float:
     if bur is not None:
         bur()
     return time.perf_counter() - t0
-
-
-def kernel_args(kind: str, inputs: tuple, out, iters: int = 1) -> dict:
-    """Analytic flops/bytes of one GCDA operator execution, derived from
-    runtime shapes (the flop model lives with the kernels in
-    ``analytics.flops_estimate``) — the span payload
-    ``roofline.from_trace()`` reads."""
-    from . import analytics
-
-    def shape(v):
-        return tuple(int(d) for d in getattr(v, "shape", ()) or ())
-
-    def nbytes(v):
-        n = getattr(v, "nbytes", None)
-        return int(n) if n is not None else 0
-
-    args: dict[str, Any] = {}
-    shapes = [shape(v) for v in inputs]
-    flops = analytics.flops_estimate(kind, shapes, iters=iters)
-    if flops:
-        args["flops"] = flops
-    total_bytes = sum(nbytes(v) for v in inputs) + nbytes(out)
-    if total_bytes:
-        args["bytes"] = total_bytes
-    if shapes:
-        args["in_shapes"] = [list(s) for s in shapes]
-    return args
 
 
 # ---------------------------------------------------------------------------

@@ -141,14 +141,12 @@ def _padded_csr(row_ptr, col_idx, edge_id, n_edges):
     return rp, jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32)
 
 
-def traverse_chain(row_ptr, col_idx, edge_id, n_vertices: int, n_edges: int,
-                   start_nids, members, edge_preds, chunk_alives, *,
-                   capacity: int, chunk: int, use_kernel: bool = False):
-    """Run a whole chain in one jit'd program. ``members[h]`` /
-    ``edge_preds[h]`` / ``chunk_alives[h]`` are per-hop tables (None =
-    unconstrained). Returns (vcols, ecols, ok): trimmed np arrays of the
-    matched path columns (hop order), or ``ok=False`` on capacity overflow
-    (caller doubles and retries)."""
+def stage_chain(row_ptr, col_idx, edge_id, n_vertices: int, n_edges: int,
+                start_nids, members, edge_preds, chunk_alives, *,
+                capacity: int, chunk: int) -> tuple:
+    """Put a chain's inputs on the device: the CSR, the per-hop tables
+    (None = unconstrained) and the padded start frontier. Returns the
+    chain program's positional arguments, for :func:`launch_chain`."""
     rp, ci, ei = _padded_csr(row_ptr, col_idx, edge_id, n_edges)
     mem, epr, cal = _device_tables(n_vertices, n_edges, chunk, members,
                                    edge_preds, chunk_alives)
@@ -159,6 +157,15 @@ def traverse_chain(row_ptr, col_idx, edge_id, n_vertices: int, n_edges: int,
     frontier = jnp.zeros((capacity,), jnp.int32).at[:C0].set(
         jnp.asarray(start_nids, jnp.int32))
     fmask = jnp.zeros((capacity,), bool).at[:C0].set(True)
+    return rp, ci, ei, frontier, fmask, mem, epr, cal
+
+
+def launch_chain(staged: tuple, *, capacity: int, chunk: int,
+                 use_kernel: bool = False):
+    """Run the chain program on staged inputs; its one host sync reads the
+    overflow flag. Returns the device's ``(vcols, ecols, count)``, or None
+    on capacity overflow (caller doubles and retries)."""
+    rp, ci, ei, frontier, fmask, mem, epr, cal = staged
     vcols, ecols, count, ovf = _chain_device(
         rp, ci, ei, frontier, fmask, mem, epr, cal, capacity=capacity,
         chunk=chunk, use_kernel=use_kernel,
@@ -166,10 +173,34 @@ def traverse_chain(row_ptr, col_idx, edge_id, n_vertices: int, n_edges: int,
     COUNTERS.launches += 1
     COUNTERS.hops += len(mem)
     if bool(ovf):               # the chain's one host sync
-        return None, None, False
+        return None
+    return vcols, ecols, count
+
+
+def read_chain(launched) -> tuple[list, list]:
+    """The matched path columns of a launch, trimmed to its count, as np
+    arrays in hop order."""
+    vcols, ecols, count = launched
     k = int(count)
     return ([np.asarray(c)[:k] for c in vcols],
-            [np.asarray(c)[:k] for c in ecols], True)
+            [np.asarray(c)[:k] for c in ecols])
+
+
+def traverse_chain(row_ptr, col_idx, edge_id, n_vertices: int, n_edges: int,
+                   start_nids, members, edge_preds, chunk_alives, *,
+                   capacity: int, chunk: int, use_kernel: bool = False):
+    """Run a whole chain in one jit'd program: :func:`stage_chain`,
+    :func:`launch_chain`, :func:`read_chain`. Returns (vcols, ecols, ok):
+    trimmed np arrays of the matched path columns (hop order), or
+    ``ok=False`` on capacity overflow (caller doubles and retries)."""
+    staged = stage_chain(row_ptr, col_idx, edge_id, n_vertices, n_edges,
+                         start_nids, members, edge_preds, chunk_alives,
+                         capacity=capacity, chunk=chunk)
+    launched = launch_chain(staged, capacity=capacity, chunk=chunk,
+                            use_kernel=use_kernel)
+    if launched is None:
+        return None, None, False
+    return (*read_chain(launched), True)
 
 
 def batched_traverse(row_ptr, col_idx, edge_id, n_vertices: int,

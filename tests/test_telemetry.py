@@ -11,6 +11,7 @@ from repro.core import (GredoEngine, Registry, Telemetry,
                         validate_chrome_trace, physical)
 from repro.core import deltastore, telemetry
 from repro.core.interbuffer import fingerprint, value_nbytes
+from repro.core.schema import Query
 from repro.data import m2bench
 
 pytestmark = pytest.mark.fast
@@ -54,6 +55,140 @@ def test_interbuffer_hit_pseudo_span(db):
     hits = [s for s in trace.spans if s.args.get("cache") == "interbuffer-hit"]
     assert hits and hits[0].name == eng.last_dag.kind
     assert eng.last_stats.interbuffer_hit
+
+
+# ---------------------------------------------------------------------------
+# Phase spans and profiler annotations
+# ---------------------------------------------------------------------------
+
+
+class _Annotations:
+    """Stand-in for ``jax.profiler.TraceAnnotation`` that logs each enter
+    and exit, to check that the annotations nest and are all closed."""
+
+    def __init__(self):
+        self.log: list = []
+        self.open: list = []
+        outer = self
+
+        class Ann:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                outer.log.append(("enter", self.name))
+                outer.open.append(self.name)
+                return self
+
+            def __exit__(self, *exc):
+                outer.log.append(("exit", self.name))
+                assert outer.open.pop() == self.name, "annotations must nest"
+
+        self.cls = Ann
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    a = _Annotations()
+    monkeypatch.setattr(telemetry, "_TraceAnnotation", a.cls)
+    return a
+
+
+def _phases(trace, sid):
+    return [c for c in trace.children_of(sid) if c.cat == telemetry.PHASE]
+
+
+@pytest.mark.parametrize("task", ["q_g3", "a3_multiply", "a_shard_reg"])
+def test_phases_lie_inside_their_span(db, task, annotations):
+    eng = GredoEngine(db, telemetry=True)
+    req = getattr(m2bench, task)()
+    (eng.query if task.startswith("q_") else eng.analyze)(req)
+    tr = eng.telemetry.last_trace()
+    root = tr.spans[0]
+    assert root.name == ("query" if task.startswith("q_") else "analyze")
+    assert [p.name for p in _phases(tr, 0)] == ["plan", "finish"]
+    want = {"DeviceMatchPattern": ["lower", "stage", "launch", "readback"],
+            "RandomAccessMatrix": ["build", "transfer"],
+            "Rel2Matrix": ["build", "transfer"]}
+    seen = set()
+    for s in tr.spans:
+        ph = _phases(tr, s.id)
+        assert sum(p.dur for p in ph) <= s.dur
+        assert all(p.ts >= s.ts and p.ts + p.dur <= s.ts + s.dur + 1e-9
+                   for p in ph)
+        if s.name in want:
+            assert [p.name for p in ph] == want[s.name]
+            seen.add(s.name)
+    assert seen
+    # every span's annotation was entered once and exited, nested alike
+    assert annotations.open == []
+    enters = [n for e, n in annotations.log if e == "enter"]
+    assert enters == [telemetry.ANNOTATION_PREFIX + s.name for s in tr.spans]
+
+
+def test_plan_span_is_within_the_plan_and_walk_time(db):
+    eng = GredoEngine(db, telemetry=True)
+    eng.query(m2bench.q_g3())
+    tr = eng.telemetry.last_trace()
+    plan = _phases(tr, 0)[0]
+    walk = eng.last_stats.seconds - sum(o["seconds"]
+                                        for o in eng.last_stats.operators
+                                        if o["executed"])
+    assert plan.name == "plan" and 0 < plan.dur <= walk
+    # the operators run between the two engine phases
+    ops = [s for s in tr.children_of(0) if s.cat != telemetry.PHASE]
+    finish = _phases(tr, 0)[1]
+    assert plan.ts + plan.dur <= ops[0].ts
+    assert ops[-1].ts + ops[-1].dur <= finish.ts
+
+
+def test_request_that_raises_leaves_no_span_open(db, annotations,
+                                                 monkeypatch):
+    eng = GredoEngine(db, telemetry=True)
+    q = m2bench.q_g3()
+    eng.query(q)
+
+    def boom(*a, **k):
+        raise RuntimeError("lost the device")
+
+    monkeypatch.setattr(physical.DeviceMatchPattern, "run", boom)
+    with pytest.raises(RuntimeError, match="lost the device"):
+        eng.query(q)
+    tr = eng.telemetry.last_trace()
+    assert tr.open_spans() == []
+    assert annotations.open == []
+    assert tr.spans[0].dur >= max(s.ts + s.dur for s in tr.spans[1:])
+    tr.close()                          # closing again changes nothing
+    assert annotations.open == []
+    # a failed plan (before any operator) closes as well
+    with pytest.raises(Exception):
+        eng.query(Query(select=("nope.x",), froms=()))
+    assert eng.telemetry.last_trace().open_spans() == []
+    assert annotations.open == []
+
+
+def test_render_counts_phases_in_their_operator(db):
+    eng = GredoEngine(db, telemetry=True)
+    eng.query(m2bench.q_g3())
+    tr = eng.telemetry.last_trace()
+    out = tr.render(top=20)
+    assert "[lower]" in out and "[plan]" in out
+    dmp = next(s for s in tr.spans if s.name == "DeviceMatchPattern")
+    line = next(l for l in out.splitlines()
+                if l.lstrip().startswith("DeviceMatchPattern")
+                and "self_ms=" in l)
+    assert float(line.split("self_ms=")[1].split()[0]) == pytest.approx(
+        dmp.dur * 1e3, abs=1e-3)        # a leaf: its phases are its own
+
+
+def test_flight_record_holds_phases_and_the_open_root(db):
+    eng = GredoEngine(db, telemetry=True)
+    eng.query(m2bench.q_g3())
+    rec = eng.observer.ring[-1]
+    cats = {s["name"]: s["cat"] for s in rec.spans}
+    assert cats["lower"] == cats["plan"] == cats["finish"] == "phase"
+    root = rec.spans[0]
+    assert root["parent"] == -1 and root["dur"] > 0
 
 
 # ---------------------------------------------------------------------------

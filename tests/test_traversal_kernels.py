@@ -1,14 +1,14 @@
 """Fused traversal kernel family + device access path: kernel == jnp oracle
 == per-hop jit matcher == host engine (property-tested), overflow retry,
 epoch-staleness discipline, optimizer lowering, runtime fallback, batched
-point lookups, and roofline attribution of the kernel spans."""
+point lookups, and the counts the device match reports."""
 from types import SimpleNamespace
 
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core import GredoEngine, optimizer, physical
+from repro.core import GredoEngine, optimizer, physical, telemetry
 from repro.core.pattern import match, plan_pattern
 from repro.core.pattern_jit import (COUNTERS, DevicePatternMatcher,
                                     StaleSnapshotError, device_match,
@@ -134,7 +134,8 @@ def test_three_way_equivalence(seed, vpred, wcut, delete_some):
                                   initial_capacity=128)
     assert _rows(jit_rel) == host
     assert _rows(pal_rel) == host
-    assert kargs["flops"] > 0 and kargs["bytes"] > 0
+    assert kargs["flavor"] == "chain" and kargs["hops"] == 1
+    assert kargs["capacity"] >= 128 and kargs["launches"] >= 1
 
 
 def test_pallas_kernel_path_matches_host():
@@ -175,6 +176,29 @@ def test_pallas_overflow_retry_counts_capacities():
     assert COUNTERS.retries > before
     assert any(cap > 128 for cap in COUNTERS.retry_caps)
     assert _rows(rel) == _rows(match(g, plan))
+
+
+def test_overflow_relaunch_repeats_stage_and_launch_phases():
+    g = _mk_graph(4, n_e=500)          # ~500 candidates >> capacity 128
+    pattern = chain_pattern("G", ("x", "A", "E", "y", "B"))
+    plan = plan_pattern(g, pattern, {}, projected=set(),
+                        force_reverse=False, enable_pushdown=True)
+    trace = telemetry.QueryTrace("t")
+    op = trace.begin("DeviceMatchPattern", cat="gcda")
+    rel, counts = device_match(g, plan, flavor="chain", initial_capacity=128,
+                               trace=trace)
+    trace.end(op)
+    n = counts["launches"]
+    assert n >= 2 and counts["capacity"] > 128
+    phases = trace.children_of(op)
+    assert [p.name for p in phases] == (["lower"] + ["stage", "launch"] * n
+                                        + ["readback"])
+    assert all(p.cat == telemetry.PHASE for p in phases)
+    assert sum(p.dur for p in phases) <= trace.spans[op].dur
+    assert _rows(rel) == _rows(match(g, plan))
+    # the per-hop jit flavor records no phases
+    device_match(g, plan, flavor="jit", trace=trace)
+    assert len(trace.spans) == 2 + len(phases)
 
 
 # ---------------------------------------------------------------------------
@@ -255,21 +279,6 @@ def test_device_query_registry_delta_and_explain(db):
     txt = eng.explain_last()
     assert "traversal kernels (this query):" in txt
     assert "via device-chain" in txt
-
-
-def test_roofline_rows_from_profile_trace(db):
-    from benchmarks import roofline
-    eng = GredoEngine(db)
-    eng.enable_telemetry()
-    eng.query(m2bench.q_g3())
-    events = eng.telemetry.collector.to_chrome()["traceEvents"]
-    rows = [r for r in roofline.from_trace(events)
-            if r["op"] == "DeviceMatchPattern"]
-    assert rows, "device match span missing flops/bytes payload"
-    r = rows[0]
-    assert r["flops"] > 0 and r["bytes"] > 0
-    assert r["achieved_gflops"] > 0 and r["roof_gflops"] > 0
-    assert 0 <= r["roofline_frac"]
 
 
 # ---------------------------------------------------------------------------
