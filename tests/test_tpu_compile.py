@@ -7,6 +7,7 @@ The topology is described inside a module-scoped fixture and never at
 import: only one process at a time may load the TPU library, and every
 xdist worker imports this file."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -76,12 +77,17 @@ def test_gcda_kernel_compiles_for_v5e(op, one_chip):
 # (vertices, edges, hops, capacity)
 CHAINS = {"q_g3_follows": (40_000, 199_665, 2, 1 << 19),
           "q_range_narrow_interested_in": (40_200, 320_355, 1, 1 << 14)}
+# a hop's cost on the chip is its count of capacity-wide gathers: seven a hop
+# and four for the path re-join at two hops; a binary-search expansion would
+# bring back a loop of gathers per hop
+MAX_GATHERS = {"q_g3_follows": 18}
 
 
 @pytest.mark.parametrize("chain", sorted(CHAINS))
 def test_device_chain_program_compiles_for_v5e(chain, one_chip):
     """The ``device-chain`` access path: the whole chain as one XLA program
-    (no Pallas kernel in it) at the capacity the optimizer chose."""
+    (no Pallas kernel in it) at the capacity the optimizer chose, with no
+    loop in it and no more gathers than the hop design needs."""
     n_vertices, n_edges, hops, cap = CHAINS[chain]
     chunk = 2048
     n_chunks = -(-n_edges // chunk)
@@ -94,5 +100,9 @@ def test_device_chain_program_compiles_for_v5e(chain, one_chip):
         (b8((n_chunks,)),) * hops,
         capacity=cap, chunk=chunk, use_kernel=False, interpret=False,
     ).compile()
-    assert "tpu_custom_call" not in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert not re.search(r"\swhile\(", text)
+    if chain in MAX_GATHERS:
+        assert len(re.findall(r"\sgather\(", text)) <= MAX_GATHERS[chain]
     assert _fits(compiled)

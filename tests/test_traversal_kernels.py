@@ -105,6 +105,110 @@ def test_batched_hop_kernel_matches_ref():
 
 
 # ---------------------------------------------------------------------------
+# jnp oracle == a plain numpy hop, array for array, on the edge cases of the
+# expansion and the compaction
+# ---------------------------------------------------------------------------
+
+
+def _np_hop(row_ptr, col_idx, edge_id, frontier, fmask, member, edge_pred,
+            chunk_alive, *, capacity, chunk):
+    """The hop's contract in numpy: expand by ``np.repeat``, filter with
+    clipped lookups, compact by a boolean mask (stable), pad."""
+    fr = np.asarray(frontier, np.int64)
+    deg = np.where(fmask, row_ptr[fr + 1] - row_ptr[fr], 0)
+    src = np.repeat(np.arange(len(fr)), deg)
+    first = np.cumsum(deg) - deg
+    pos = row_ptr[fr][src] + np.arange(len(src)) - first[src]
+    overflowed = len(src) > capacity
+    src, pos = src[:capacity], pos[:capacity]
+    dst, eid = col_idx[pos], edge_id[pos]
+    clip = lambda i, t: t[np.clip(i, 0, len(t) - 1)]
+    keep = (clip(dst, member) & clip(eid // chunk, chunk_alive)
+            & clip(eid, edge_pred))
+    k = int(keep.sum())
+    out = [np.zeros(capacity, np.int64), np.full(capacity, -1, np.int64),
+           np.full(capacity, -1, np.int64)]
+    for o, v in zip(out, (src, dst, eid)):
+        o[:k] = v[keep]
+    return (*out, k, overflowed)
+
+
+def _hop_case(name, capacity=32, chunk=4):
+    """Hop inputs for one edge case: the frontier's degrees, which of its
+    slots are valid, and the zone-map table."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    degs = {"degree0_between": [3, 0, 0, 2, 0, 4, 1, 0, 5],
+            "empty_frontier": [2, 3, 1],
+            "no_edges": [0, 0, 0, 0],
+            "total_at_capacity": [9, 7, 0, 8, 8],
+            "total_past_capacity": [9, 7, 0, 8, 9],
+            "dead_chunks": [5, 6, 0, 7, 3],
+            "short_chunk_table": [5, 6, 0, 7, 3]}[name]
+    n = len(degs)
+    row_ptr = np.zeros(n + 1, np.int64)
+    row_ptr[1:] = np.cumsum(degs)
+    m = max(int(row_ptr[-1]), 1)        # one dummy entry for an edgeless graph
+    col_idx = rng.integers(0, n, m)
+    edge_id = rng.permutation(m)
+    member = rng.random(n) < 0.8
+    edge_pred = rng.random(m) < 0.8
+    nch = -(-m // chunk)
+    chunk_alive = np.ones(nch, bool)
+    if name == "dead_chunks":
+        chunk_alive[::2] = False
+    if name == "short_chunk_table":
+        chunk_alive = chunk_alive[:nch // 2]
+        chunk_alive[-1] = False         # tids past the table read this entry
+    frontier = np.zeros(capacity, np.int32)
+    frontier[:n] = np.arange(n)
+    fmask = np.zeros(capacity, bool)
+    fmask[:n] = name != "empty_frontier"
+    if name == "degree0_between":
+        fmask[6] = False                # an invalid slot between live rows
+    return (row_ptr, col_idx, edge_id, frontier, fmask, member, edge_pred,
+            chunk_alive), dict(capacity=capacity, chunk=chunk)
+
+
+HOP_CASES = ["degree0_between", "empty_frontier", "no_edges",
+             "total_at_capacity", "total_past_capacity", "dead_chunks",
+             "short_chunk_table"]
+
+
+@pytest.mark.parametrize("case", HOP_CASES)
+def test_fused_hop_ref_matches_numpy_hop(case):
+    args, kw = _hop_case(case)
+    want = _np_hop(*args, **kw)
+    got = kref.fused_hop_ref(*args, **kw)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), w)
+    total = int(np.where(args[4], np.diff(args[0])[args[3]], 0).sum())
+    assert bool(got[4]) == (total > kw["capacity"])
+    if case == "total_at_capacity":
+        assert total == kw["capacity"] and not bool(got[4])
+    if case == "total_past_capacity":
+        assert total == kw["capacity"] + 1 and int(got[3]) > 0
+
+
+def test_batched_hop_ref_matches_numpy_hop_per_query():
+    args, kw = _hop_case("degree0_between")
+    row_ptr, col_idx, edge_id, _, _, member, edge_pred, chunk_alive = args
+    rng = np.random.default_rng(15)
+    n, B, capacity = len(row_ptr) - 1, 4, kw["capacity"]
+    frontiers = np.zeros((B, capacity), np.int32)
+    fmasks = np.zeros((B, capacity), bool)
+    for q in range(B):                  # query 0 has an empty frontier
+        frontiers[q, :2 * q] = rng.integers(0, n, 2 * q)
+        fmasks[q, :2 * q] = rng.random(2 * q) < 0.8
+    got = kref.batched_hop_ref(row_ptr, col_idx, edge_id, frontiers, fmasks,
+                               member, edge_pred, chunk_alive, **kw)
+    for q in range(B):
+        want = _np_hop(row_ptr, col_idx, edge_id, frontiers[q], fmasks[q],
+                       member, edge_pred, chunk_alive, **kw)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g)[q], w)
+
+
+# ---------------------------------------------------------------------------
 # Property test: host == per-hop jit == whole-chain path, including
 # tombstone-then-compact write bursts and overflow-forcing capacities
 # ---------------------------------------------------------------------------
