@@ -1,9 +1,11 @@
 """The comparison that decides ``correct``.
 
 After the window has closed, the answers the timed path produced (or a
-sample of them drawn from the seed) are compared with the plain reference
-(``bench/reference.py``). Each number compared has its limit in
-``bench/limits/<number>.json``, with the readings it was set from.
+sample of them drawn from the seed) are compared with the configuration's
+plain reference, the module the caller passes as ``ref``
+(``bench/reference.py`` unless the configuration names another). Each
+number compared has its limit in ``bench/limits/<number>.json``, with the
+readings it was set from.
 
 GCDI (``kind: query``): ``wrong_rows``, the rows by which the sampled
 answers and the reference's differ as bags, summed; ``failed_requests``,
@@ -26,8 +28,6 @@ import os
 
 import numpy as np
 
-from bench import reference
-
 HERE = os.path.dirname(os.path.abspath(__file__))
 NUMBERS = {"query": ("wrong_rows", "failed_requests"),
            "analyze": ("mm_err", "sim_err", "reg_err", "failed_requests")}
@@ -38,11 +38,12 @@ def limit(name: str) -> float:
         return float(json.load(f)["limit"])
 
 
-def numbers(kind: str, kept: list, raw: dict, mix: dict, failed: int,
-            control: bool = False) -> dict:
+def numbers(kind: str, kept: list, raw: dict, mix: dict, failed: int, *,
+            ref, control: bool = False) -> dict:
     """``kept`` holds ``(entry, answer, rows)``: for a GCDI request the
     selected columns, for a MULTIPLY/SIMILARITY task the sampled output rows
-    and their indices, for a REGRESSION the weights."""
+    and their indices, for a REGRESSION the weights. ``ref`` is the
+    reference module, with ``bench/reference.py``'s functions."""
     out = {"failed_requests": float(failed)}
     if kind == "query":
         want: dict = {}
@@ -51,12 +52,12 @@ def numbers(kind: str, kept: list, raw: dict, mix: dict, failed: int,
         for entry, cols, _ in kept:
             key = (entry.template, entry.index)
             if key not in want:
-                want[key] = reference.relation(raw, entry.spec)
+                want[key] = ref.relation(raw, entry.spec)
             if control:
                 if key not in low:
-                    low[key] = reference.distinct(want[key])
+                    low[key] = ref.distinct(want[key])
                 cols = low[key]
-            off += reference.rows_off(cols, want[key])
+            off += ref.rows_off(cols, want[key])
         out["wrong_rows"] = float(off)
         return out
 
@@ -68,7 +69,7 @@ def numbers(kind: str, kept: list, raw: dict, mix: dict, failed: int,
         a = entry.spec["analytics"]
         key = (entry.template, entry.index)
         if key not in mats:
-            mats[key] = [reference.matrix(raw, entry.spec, inp)
+            mats[key] = [ref.matrix(raw, entry.spec, inp)
                          for inp in a["inputs"]]
         x = mats[key][0]
         if a["op"] == "REGRESSION":
@@ -76,20 +77,20 @@ def numbers(kind: str, kept: list, raw: dict, mix: dict, failed: int,
                     float(reg["l2"]))
             for low in {False, control}:
                 if (key, low) not in fits:
-                    fits[key, low] = reference.regression(*args, lower=low)
+                    fits[key, low] = ref.regression(*args, lower=low)
             want = fits[key, False]
             if control:
                 got = fits[key, True]
         else:
-            fn = (reference.gram_rows if a["op"] == "MULTIPLY"
-                  else reference.cosine_rows)
+            fn = (ref.gram_rows if a["op"] == "MULTIPLY"
+                  else ref.cosine_rows)
             if got.shape[1] != x.shape[0]:      # the program's n is wrong
                 errs[a["op"]].append(float("inf"))
                 continue
             want = fn(x, rows)
             if control:
                 got = fn(x, rows, lower=True)
-        errs[a["op"]].append(reference.rel_err(got, want))
+        errs[a["op"]].append(ref.rel_err(got, want))
     out["mm_err"] = max(errs["MULTIPLY"])
     out["sim_err"] = max(errs["SIMILARITY"])
     out["reg_err"] = max(errs["REGRESSION"])

@@ -23,7 +23,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("seeds", type=int, nargs="+")
     args = ap.parse_args(argv)
-    from bench import check, compile_cache, reference, run
+    from bench import check, compile_cache, run
     _, w, _, _ = run.cell(args.workload)
     devices = run.require_chips(int(w["chips"]))
     log = compile_cache.CompileLog()
@@ -32,8 +32,8 @@ def main(argv=None) -> int:
         m = run.measure(args.workload, seed, args.seconds, False,
                         devices=devices, log=log)
         kind = m.run.kind
-        prog = check.numbers(kind, m.kept, m.raw, m.mix, m.failed)
-        ctl = check.numbers(kind, m.kept, m.raw, m.mix, m.failed,
+        prog = check.numbers(kind, m.kept, m.raw, m.mix, m.failed, ref=m.ref)
+        ctl = check.numbers(kind, m.kept, m.raw, m.mix, m.failed, ref=m.ref,
                             control=True)
         line = {"workload": args.workload, "seed": seed,
                 "requests": len(m.run.records), "kept": len(m.kept),
@@ -41,8 +41,8 @@ def main(argv=None) -> int:
         if kind == "query":
             specs = {(e.template, e.index): e.spec for e, _, _ in m.kept}
             line["float32_rows_off"] = sum(
-                reference.rows_off(reference.relation(m.raw, s, lower=True),
-                                   reference.relation(m.raw, s))
+                m.ref.rows_off(m.ref.relation(m.raw, s, lower=True),
+                               m.ref.relation(m.raw, s))
                 for s in specs.values())
         print(json.dumps(line), flush=True)
     return 0

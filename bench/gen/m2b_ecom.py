@@ -23,6 +23,8 @@ each configuration's ``assumed``:
 
 ``generate`` returns ``(db, raw)``: the program's ``Database`` and the same
 data as plain numpy columns, which is all the plain reference reads.
+``PARAMS`` and ``INPUTS`` are the schema's own parameter and input kinds,
+which ``bench/workload.py`` looks up by the names a mix gives.
 """
 from __future__ import annotations
 
@@ -46,8 +48,10 @@ def counts(sf: int) -> dict:
             "Orders": 10_000 * sf, "Persons": 2_500 * sf, "Tags": N_TAGS}
 
 
-def generate(sf: int, seed: int):
-    n = counts(sf)
+def generate(sf, seed: int):
+    """The data at scale factor ``sf`` (a whole number, as the configuration
+    writes it) from ``seed``."""
+    n = counts(int(sf))
     n_products, n_customers = n["Product"], n["Customer"]
     n_orders, n_persons = n["Orders"], n["Persons"]
     rng = np.random.default_rng(seed)
@@ -157,3 +161,32 @@ def build_indexes(db: Database):
     im.create("Interested_in", "popularity", label="Tags")
     im.create("Interested_in", "content", label="Tags")
     return im
+
+
+def order_keys(params: dict, count: int, rng, raw: dict) -> list[dict]:
+    """``count`` random orders: ``$oid``, its customer ``$cid`` and that
+    customer's person ``$pid``."""
+    orders = raw["tables"]["Orders"]
+    person = raw["tables"]["Customer"]["person_id"]
+    rows = rng.integers(0, len(orders["order_id"]), count)
+    return [{"oid": int(orders["order_id"][r]),
+             "cid": int(orders["customer_id"][r]),
+             "pid": int(person[orders["customer_id"][r]])} for r in rows]
+
+
+def purchase_labels(raw: dict, query: dict, group: str, title: str
+                    ) -> list[float]:
+    """A label vector: 1.0 per distinct ``group`` (customer id) of the
+    query's answer, in ascending order (the rows of the feature matrix),
+    that ordered a product whose title starts with ``title``; else 0.0."""
+    from bench import reference
+    ids = np.unique(reference.relation(raw, {**query, "select": [group]})[0])
+    t = raw["tables"]
+    hit = np.char.startswith(t["Product"]["title"].astype(str), title)
+    o = t["Orders"]
+    buyers = np.unique(o["customer_id"][hit[o["product_id"]]])
+    return np.isin(ids, buyers).astype(np.float64).tolist()
+
+
+PARAMS = {"order_keys": order_keys}
+INPUTS = {"purchase_labels": purchase_labels}

@@ -3,22 +3,28 @@
     python bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 The cell (``BENCHMARK.json``'s ``workloads``) names a configuration
-(``bench/configs/<config>.json``: the generator, its scale, the engine
-settings) and a traffic mix (``bench/traffic/<mix>.json``). Set-up
-places JAX's compile cache, makes the data from the seed, builds the
-indexes and the engine, and runs every request of the pool once, which
-compiles every program the window uses. The window is a closed loop with
-one client: each request is sent when the previous one has returned (a
-GCDIA task has returned when its output is ready on the device). It runs
-whole rounds of the mix (``bench/workload.py``) and ends with the round in
-which ``--seconds`` have passed. The outputs kept for the check are held
-as the program returned them; once the window has closed, the answers are
-read from them, the program's state is freed, and they are compared with
-the plain reference (``bench/check.py``).
+and a traffic mix (``bench/traffic/<mix>.json``). The configuration's
+file names its generator (``bench/gen/<generator>.py``: ``generate``,
+``build_indexes`` and its parameter and input kinds), its scale ``sf``,
+which reaches the generator as the file writes it, the engine settings,
+and optionally its plain reference (``"reference": "<module>"``, the file
+``bench/<module>.py``; ``bench/reference.py`` by default). So a new
+configuration or mix lands as new files. Set-up places JAX's compile
+cache, makes the data from the seed, builds the indexes and the engine,
+and runs every request of the pool once (for a session mix, one session
+per pool index), which compiles every program the window uses. The window
+is a closed loop with one client: each request is sent when the previous
+one has returned (a GCDIA task has returned when its output is ready on
+the device). It runs whole rounds of the mix (``bench/workload.py``) and
+ends with the round in which ``--seconds`` have passed. The outputs kept
+for the check are held as the program returned them; once the window has
+closed, the answers are read from them, the program's state is freed, and
+they are compared with the plain reference (``bench/check.py``).
 
 With ``--trace 0`` the result line carries the cell's end-to-end metrics;
 with ``--trace 1`` the engine fences device work inside its operator times,
-the profiler records the window, and the line carries the cell's per-layer
+the profiler records the window, each request's record keeps its operator
+times and inter-buffer counters, and the line carries the cell's per-layer
 metrics, the device's busy seconds and a breakdown. Each metric is read by
 ``bench/metrics/<name>.py``.
 
@@ -47,6 +53,7 @@ ANNOTATION = "bench:"
 KEEP_FIRST = 4          # each template's answer kept: one of its first four
 KEEP_SHARE = {"query": 0.25, "analyze": 0.0}    # and this share of the rest
 SAMPLE_ROWS = 128       # rows of a kept n x n output compared with the reference
+IB_COUNTERS = ("hits", "misses", "bypasses", "evictions")
 
 
 def steady_allocator() -> None:
@@ -114,12 +121,15 @@ class Run:
 
 
 def _op_stats(eng) -> dict:
+    """Self seconds of the executed operators by kind, and the rows of the
+    executed and the inter-buffer-served ones."""
     st = eng.last_stats
     ops: dict = {}
     rows: dict = {}
     for o in st.operators:
         if o["executed"]:
             ops[o["op"]] = ops.get(o["op"], 0.0) + o["seconds"]
+        if o["executed"] or o["cached"]:
             rows[o["op"]] = o["rows"]
     return {"seconds": st.seconds, "op_s": ops, "op_rows": rows}
 
@@ -165,6 +175,7 @@ class Measured:
     workload: dict
     mix: dict
     raw: dict                   # the data, as the reference reads it
+    ref: object                 # the configuration's reference module
     kept: list                  # (entry, answer, rows) drawn for the check
     failed: int
     run: Run
@@ -200,7 +211,9 @@ def measure(workload: str, seed: int, seconds: float, trace: bool, *,
 
     gen = _load(os.path.join(BENCH, "gen", f"{cfg['generator']}.py"),
                 f"bench_gen_{cfg['generator']}")
-    db, raw = gen.generate(int(cfg["sf"]), seed)
+    ref_name = cfg.get("reference", "reference")
+    ref = _load(os.path.join(BENCH, f"{ref_name}.py"), f"bench_ref_{ref_name}")
+    db, raw = gen.generate(cfg["sf"], seed)
     gen.build_indexes(db)
     e = cfg["engine"]
     eng = GredoEngine(db, mode=e["mode"], n_shards=int(e["n_shards"]),
@@ -210,24 +223,27 @@ def measure(workload: str, seed: int, seconds: float, trace: bool, *,
 
     kind = mix["kind"]
     iters = int(mix.get("iters", 100))
-    pool = wl.build_pool(mix, seed, raw)
-    n_pool = sum(len(v) for v in pool.values())
-    rounds = wl.rounds(pool, seed)
+    pool = wl.build_pool(mix, seed, raw, gen)
+    session = mix.get("session")
+    warm = wl.warmup(pool, session)
+    n_warm = sum(map(len, warm))
+    rounds = wl.rounds(pool, seed, session)
     keep_rng = np.random.default_rng([seed, 3])
     keep_at = {t: int(keep_rng.integers(KEEP_FIRST)) for t in sorted(pool)}
     share = KEEP_SHARE[kind]
     seen = dict.fromkeys(pool, 0)
 
-    def serve(ent):
+    def serve(ent, first: bool):
+        """One request; ``first`` when it opens a round (a session)."""
         if kind == "query":
             return eng.query(ent.request)
-        if mix.get("clear_interbuffer"):
+        if mix.get("clear_interbuffer") or (session and first):
             eng.interbuffer.clear()
         return jax.block_until_ready(eng.analyze(ent.request, iters=iters))
 
-    for entries in pool.values():
-        for ent in entries:
-            serve(ent)
+    for batch in warm:
+        for i, ent in enumerate(batch):
+            serve(ent, i == 0)
     t_warm = time.perf_counter()
     compiles_setup = log.requests
 
@@ -244,13 +260,13 @@ def measure(workload: str, seed: int, seconds: float, trace: bool, *,
     t0 = time.perf_counter()
     t_end = t0 + seconds
     while time.perf_counter() < t_end:      # whole rounds
-        for ent in next(rounds):
+        for i, ent in enumerate(next(rounds)):
             ctx = (jax.profiler.TraceAnnotation(ANNOTATION + ent.template)
                    if trace else contextlib.nullcontext())
             with ctx:
                 a = time.perf_counter()
                 try:
-                    out = serve(ent)
+                    out = serve(ent, i == 0)
                 except Exception as exc:  # a failed request counts; the run goes on
                     failed += 1
                     print(f"request {len(records) + failed} ({ent.template}) "
@@ -263,6 +279,8 @@ def measure(workload: str, seed: int, seconds: float, trace: bool, *,
                    "ms": (b - a) * 1e3}
             if trace:
                 rec.update(_op_stats(eng))
+                d = eng.last_interbuffer_delta
+                rec["interbuffer"] = {k: d.get(k, 0) for k in IB_COUNTERS}
                 if kind == "analyze":
                     rec["gcda"] = _gcda_shape(ent, rec, iters)
             records.append(rec)
@@ -286,11 +304,11 @@ def measure(workload: str, seed: int, seconds: float, trace: bool, *,
         peak = max(peak, int(st.get("peak_bytes_in_use", 0)))
     kept = _answers(kind, kept, np.random.default_rng([seed, 4]))
     n_rounds = sum(seen.values()) // len(pool)
-    del eng, db, pool, rounds
+    del eng, db, pool, rounds, warm
 
     print(f"setup: {setup_s} s = start {t_dev - T_START} s + data and "
           f"indexes {t_load - t_dev} s + warm-up {t_warm - t_load} s "
-          f"({n_pool} requests); compile requests {compiles_setup - requests0} "
+          f"({n_warm} requests); compile requests {compiles_setup - requests0} "
           f"taking {log.seconds - seconds0} s, cache hits {log.hits}, misses "
           f"{log.misses}, cache {cache_dir}", file=sys.stderr)
     print(f"window: {len(records)} requests in {window_s} s "
@@ -303,9 +321,18 @@ def measure(workload: str, seed: int, seconds: float, trace: bool, *,
     print("latency ms by template (count, median, max): " + "; ".join(
         f"{t} {len(v)} {float(np.median(v))} {max(v)}"
         for t, v in sorted(by.items())), file=sys.stderr)
+    if trace:
+        ib: dict = {}
+        for r in records:
+            t = ib.setdefault(r["template"], dict.fromkeys(IB_COUNTERS, 0))
+            for k in IB_COUNTERS:
+                t[k] += r["interbuffer"][k]
+        print("inter-buffer by template (hits, misses, bypasses, evictions): "
+              + "; ".join(f"{t} " + " ".join(str(v[k]) for k in IB_COUNTERS)
+                          for t, v in sorted(ib.items())), file=sys.stderr)
     run = Run(kind, setup_s, window_s, records, trace=red,
               device_kind=devices[0].device_kind)
-    return Measured(bench, w, mix, raw, kept, failed, run, peak, devices,
+    return Measured(bench, w, mix, raw, ref, kept, failed, run, peak, devices,
                     compiles_window)
 
 
@@ -320,7 +347,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     t_ref = time.perf_counter()
     run = m.run
     ok, table = check.verdict(run.kind, check.numbers(
-        run.kind, m.kept, m.raw, m.mix, m.failed))
+        run.kind, m.kept, m.raw, m.mix, m.failed, ref=m.ref))
     print(f"reference: {time.perf_counter() - t_ref} s", file=sys.stderr)
 
     names = m.bench["per_layer"] if trace else m.bench["end_to_end"]

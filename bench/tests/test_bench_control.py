@@ -10,7 +10,7 @@ through ``bench/control.py``.
 import numpy as np
 import pytest
 
-from bench import check, run, workload
+from bench import check, reference, run, workload
 from bench.gen import m2b_ecom
 
 SEED = 2**31 + 999
@@ -23,18 +23,22 @@ def small(name):
 
 @pytest.fixture(scope="module")
 def answers():
-    """One program answer per pool entry template of each mix, at sf=1."""
+    """One program answer per pool entry template of each mix, at sf=1; the
+    session's in its order from a cleared inter-buffer, so that A2 and A1
+    take A3's matrix from it."""
     import jax.numpy as jnp
     from repro.core import GredoEngine
     db, raw = m2b_ecom.generate(1, SEED)
     m2b_ecom.build_indexes(db)
     eng = GredoEngine(db, mode="gredo")
     out = {}
-    for mix_name in ("gcdi_mix", "gcdia_cold"):
+    for mix_name in ("gcdi_mix", "gcdia_cold", "gcdia_session"):
         mix = workload.load_mix(mix_name)
+        pool = workload.build_pool(mix, SEED, raw, m2b_ecom)
+        eng.interbuffer.clear()
         kept = []
-        for entries in workload.build_pool(mix, SEED, raw).values():
-            e = entries[0]
+        for name in mix.get("session", pool):
+            e = pool[name][0]
             if mix["kind"] == "query":
                 t = eng.query(e.request)
                 kept.append((e, [np.asarray(t.col(a)) for a in e.spec["select"]],
@@ -47,16 +51,19 @@ def answers():
                 y = eng.analyze(e.request, iters=mix["iters"])
                 rows = np.arange(0, y.shape[0], 53)
                 kept.append((e, np.asarray(y[jnp.asarray(rows)]), rows))
-        out[mix["kind"]] = (kept, raw, mix)
+        out["session" if "session" in mix else mix["kind"]] = (kept, raw, mix)
     return out
 
 
-@pytest.mark.parametrize("kind", ["query", "analyze"])
+@pytest.mark.parametrize("kind", ["query", "analyze", "session"])
 def test_program_passes_and_control_fails(answers, kind):
     kept, raw, mix = answers[kind]
-    ok, table = check.verdict(kind, check.numbers(kind, kept, raw, mix, 0))
+    kind = mix["kind"]
+    ok, table = check.verdict(kind, check.numbers(kind, kept, raw, mix, 0,
+                                                  ref=reference))
     assert ok, table
     ok, table = check.verdict(kind, check.numbers(kind, kept, raw, mix, 0,
+                                                  ref=reference,
                                                   control=True))
     assert not ok, table
     if kind == "analyze":
@@ -71,7 +78,8 @@ def _drop_last_row(t):
     return t.take(np.arange(max(t.nrows - 1, 0)))
 
 
-@pytest.mark.parametrize("name", ["ecom_gcdi_mix", "ecom_gcdia_cold"])
+@pytest.mark.parametrize("name", ["ecom_gcdi_mix", "ecom_gcdia_cold",
+                                  "ecom_gcdia_session"])
 def test_run_is_correct_and_an_altered_answer_is_not(monkeypatch, name):
     from repro.core import GredoEngine
     cfg, mix = small(name)
@@ -89,3 +97,24 @@ def test_run_is_correct_and_an_altered_answer_is_not(monkeypatch, name):
                             lambda self, t, **kw: real(self, t, **kw) + 1e-3)
     line = run.run_cell(name, SEED, 1.0, False, config=cfg)
     assert not line["correct"], line["checks"]
+
+
+def test_a_wrong_interbuffer_hit_is_not_correct(monkeypatch):
+    """At sf=1 a session's A2 and A1 take A3's matrix from the inter-buffer
+    (n x n = 1,617^2 floats fit in it); a hit that hands back an altered
+    matrix has to come out not correct."""
+    from repro.core.interbuffer import InterBuffer
+    name = "ecom_gcdia_session"
+    cfg, _ = small(name)
+    hits = []
+    real = InterBuffer.get
+
+    def wrong(self, key):
+        got = real(self, key)
+        if got is None or hasattr(got, "columns"):
+            return got
+        hits.append(key)
+        return got.at[0].set(1.0 - got[0])
+    monkeypatch.setattr(InterBuffer, "get", wrong)
+    line = run.run_cell(name, SEED, 1.0, False, config=cfg)
+    assert hits and not line["correct"], line["checks"]

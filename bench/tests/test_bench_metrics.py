@@ -89,3 +89,22 @@ def test_work_at_known_shapes():
 def test_peaks_refuse_an_unknown_kind():
     with pytest.raises(KeyError, match="no peaks for device kind"):
         work.roofline_s(1.0, 1.0, "TPU v99")
+
+
+def test_interbuffer_miss_share_is_over_the_gcdia_tasks_lookups():
+    run = closed_loop([50.0] * 6, "analyze")
+    counts = [(0, 3), (1, 1), (1, 1), (0, 3), (1, 1), (1, 1)]  # A3, A2, A1 x 2
+    for r, (h, m) in zip(run.records, counts):
+        r["interbuffer"] = {"hits": h, "misses": m, "bypasses": 0,
+                            "evictions": 3 * (h == 0)}
+    assert reader("interbuffer_miss.gcdia")(run) == pytest.approx(10 / 14)
+    for r in run.records:
+        r["interbuffer"].update(hits=0, misses=3)
+    assert reader("interbuffer_miss.gcdia")(run) == 1.0
+    untraced = closed_loop([50.0] * 6, "analyze")
+    assert reader("interbuffer_miss.gcdia")(untraced) is None
+    queries = closed_loop([50.0] * 6, "query")
+    for r in queries.records:
+        r["interbuffer"] = {"hits": 0, "misses": 1, "bypasses": 0,
+                            "evictions": 0}
+    assert reader("interbuffer_miss.gcdia")(queries) is None

@@ -66,7 +66,7 @@ def test_every_seed_has_the_same_gcda_rows():
     shapes = set()
     for seed in (SEED, SEED + 1, 7):
         _, raw = m2b_ecom.generate(1, seed)
-        (e,) = workload.build_pool(mix, seed, raw)["A1"]
+        (e,) = workload.build_pool(mix, seed, raw, m2b_ecom)["A1"]
         x, y = [reference.matrix(raw, e.spec, i)
                 for i in e.spec["analytics"]["inputs"]]
         g1 = reference.relation(raw, e.spec["query"])
@@ -105,9 +105,9 @@ def test_orders_equal_shredded_documents(ecom):
 def test_pool_and_sequence_are_deterministic(ecom, mix):
     _, _, raw = ecom
     m = workload.load_mix(mix)
-    a = workload.build_pool(m, SEED, raw)
-    b = workload.build_pool(m, SEED, raw)
-    c = workload.build_pool(m, SEED + 1, raw)
+    a = workload.build_pool(m, SEED, raw, m2b_ecom)
+    b = workload.build_pool(m, SEED, raw, m2b_ecom)
+    c = workload.build_pool(m, SEED + 1, raw, m2b_ecom)
     assert [e.spec for es in a.values() for e in es] == \
         [e.spec for es in b.values() for e in es]
     assert sorted(a) == sorted(c) == sorted(m["templates"])
@@ -132,8 +132,8 @@ def test_parameter_kinds_bind_per_seed(ecom):
         "range": {**q, "where": [["Product.price", "range", "$lo", "$hi"]],
                   "params": {"kind": "uniform_window", "count": 3,
                              "lo": [1, 499], "width": 0.5}}}}
-    a = workload.build_pool(mix, SEED, raw)
-    b = workload.build_pool(mix, SEED + 1, raw)
+    a = workload.build_pool(mix, SEED, raw, m2b_ecom)
+    b = workload.build_pool(mix, SEED + 1, raw, m2b_ecom)
     assert len(a["point"]) == 4 and len(a["range"]) == 3
     assert a["point"][0].spec != b["point"][0].spec
     o = raw["tables"]["Orders"]
@@ -153,7 +153,8 @@ def test_every_template_equals_single_mode_and_the_reference(request, data):
     _, db, raw = request.getfixturevalue(data)
     gredo = GredoEngine(db, mode="gredo")
     single = GredoEngine(db, mode="single")
-    pool = workload.build_pool(workload.load_mix("gcdi_mix"), SEED, raw)
+    pool = workload.build_pool(workload.load_mix("gcdi_mix"), SEED, raw,
+                                m2b_ecom)
     for name, entries in pool.items():
         for e in entries[:2]:
             got = gredo.query(e.request)
@@ -170,7 +171,7 @@ def test_gcdia_templates_run_through_the_engine(ecom):
     _, db, raw = ecom
     eng = GredoEngine(db, mode="gredo")
     mix = workload.load_mix("gcdia_cold")
-    pool = workload.build_pool(mix, SEED, raw)
+    pool = workload.build_pool(mix, SEED, raw, m2b_ecom)
     reg = mix["regression"]
     for name, (e,) in pool.items():
         out = np.asarray(eng.analyze(e.request, iters=mix["iters"]))
@@ -190,7 +191,7 @@ def test_gcdia_templates_run_through_the_engine(ecom):
 def test_a1_labels_are_the_yogurt_buyers_of_the_feature_rows(ecom):
     _, _, raw = ecom
     mix = workload.load_mix("gcdia_cold")
-    (e,) = workload.build_pool(mix, SEED, raw)["A1"]
+    (e,) = workload.build_pool(mix, SEED, raw, m2b_ecom)["A1"]
     x, y = [reference.matrix(raw, e.spec, i)
             for i in e.spec["analytics"]["inputs"]]
     assert y.shape == (x.shape[0],) and set(np.unique(y)) == {0.0, 1.0}
@@ -213,7 +214,7 @@ def test_a1_sigmoid_stays_unsaturated(ecom):
     from bench import check
     _, _, raw = ecom
     mix = workload.load_mix("gcdia_cold")
-    (e,) = workload.build_pool(mix, SEED, raw)["A1"]
+    (e,) = workload.build_pool(mix, SEED, raw, m2b_ecom)["A1"]
     x, y = [reference.matrix(raw, e.spec, i)
             for i in e.spec["analytics"]["inputs"]]
     reg = mix["regression"]

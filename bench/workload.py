@@ -11,20 +11,24 @@ A mix file holds its templates as data. A GCDI template is a query spec::
      "where": [["t.content", "==", "food"], ["Product.price", "range", "$lo", "$hi"]]}
 
 A GCDIA template adds ``"analytics": {"op": ..., "inputs": [...]}`` beside
-its ``"query"``. An input ``["purchase_labels", "Customer.id", <title>]``
-is a label vector, built here from the data: one entry per distinct
-customer id of the query's answer in ascending order (the rows of the
-feature matrix), 1 where that customer ordered a product whose title
-starts with ``<title>``; the program gets it as a ``const`` input. A value
-written ``"$name"`` is drawn per pool entry by the
-template's ``"params"``: ``order_keys`` picks random orders and binds
-``$oid``, ``$cid`` and ``$pid`` (the order, its customer, that customer's
-person); ``uniform_window`` binds ``$lo`` uniform in ``lo`` and
-``$hi = $lo + width``. ``count`` entries are drawn per template.
+its ``"query"``. An input whose kind the configuration's generator lists in
+its ``INPUTS`` table (``{kind: fn}``) is made here from the data:
+``fn(raw, query, *args)`` gives a vector, which the program gets as a
+``const`` input; the e-commerce generator's ``purchase_labels`` is one. A
+value written ``"$name"`` is drawn per pool entry by the template's
+``"params"``, whose ``kind`` is ``uniform_window`` (binds ``$lo`` uniform in
+``lo`` and ``$hi = $lo + width``) or a kind in the generator's ``PARAMS``
+table (``{kind: fn}``, ``fn(params, count, rng, raw)`` gives one dict of
+bindings per entry). ``count`` entries are drawn per template.
 
-The window is made of rounds: each round holds every template once, in an
-order drawn from the seed, and each request of a template takes one of its
-pool entries at random. Every seed thus sends the same mix of templates.
+The window is made of rounds. By default each round holds every template
+once, in an order drawn from the seed, and each request of a template
+takes one of its pool entries at random. Every seed thus sends the same
+mix of templates. A mix that names a ``"session"``, an ordered list of its
+templates, runs that list as each round instead: always in that order, all
+of a round's requests on one pool index drawn from the seed, so their pools
+are of one size. The harness clears the inter-buffer once, inside the
+first request of each session, and never between its requests.
 """
 from __future__ import annotations
 
@@ -52,8 +56,16 @@ class Entry:
 
 
 def load_mix(name: str) -> dict:
+    """The mix ``bench/traffic/<name>.json``; a ``"session"`` must list every
+    template, name no other, and not go with ``clear_interbuffer``."""
     with open(os.path.join(HERE, "traffic", f"{name}.json")) as f:
-        return json.load(f)
+        mix = json.load(f)
+    session = mix.get("session")
+    if session is not None and (set(session) != set(mix["templates"])
+                                or mix.get("clear_interbuffer")):
+        raise ValueError(f"mix {name!r}: a session lists every template and "
+                         "the inter-buffer is cleared only at its start")
+    return mix
 
 
 def _bind(obj, params: dict):
@@ -90,68 +102,77 @@ def build_request(spec: dict):
                      analytics=AnalyticsTask(a["op"], inputs))
 
 
-def _draw_params(kind: dict, count: int, rng, raw: dict) -> list[dict]:
-    if kind["kind"] == "order_keys":
-        orders = raw["tables"]["Orders"]
-        person = raw["tables"]["Customer"]["person_id"]
-        rows = rng.integers(0, len(orders["order_id"]), count)
-        return [{"oid": int(orders["order_id"][r]),
-                 "cid": int(orders["customer_id"][r]),
-                 "pid": int(person[orders["customer_id"][r]])} for r in rows]
-    if kind["kind"] == "uniform_window":
-        a, b = kind["lo"]
-        lo = rng.uniform(a, b, count)
-        return [{"lo": float(x), "hi": float(x + kind["width"])} for x in lo]
-    raise ValueError(f"unknown parameter kind {kind['kind']!r}")
+def uniform_window(params: dict, count: int, rng, raw: dict) -> list[dict]:
+    """``$lo`` uniform in ``params["lo"]``, ``$hi = $lo + width``."""
+    a, b = params["lo"]
+    lo = rng.uniform(a, b, count)
+    return [{"lo": float(x), "hi": float(x + params["width"])} for x in lo]
 
 
-def purchase_labels(raw: dict, query: dict, group: str, title: str
-                    ) -> list[float]:
-    """1.0 per distinct ``group`` (customer id) of the query's answer, in
-    ascending order, that ordered a product whose title starts with
-    ``title``; else 0.0."""
-    from bench import reference
-    ids = np.unique(reference.relation(raw, {**query, "select": [group]})[0])
-    t = raw["tables"]
-    hit = np.char.startswith(t["Product"]["title"].astype(str), title)
-    o = t["Orders"]
-    buyers = np.unique(o["customer_id"][hit[o["product_id"]]])
-    return np.isin(ids, buyers).astype(np.float64).tolist()
+PARAMS = {"uniform_window": uniform_window}
 
 
-def _labels(spec: dict, raw: dict) -> dict:
+def _inputs(spec: dict, raw: dict, gen) -> dict:
+    """The spec with each analytics input of a kind in ``gen.INPUTS`` made
+    into a ``const`` vector."""
     if "analytics" not in spec:
         return spec
     a = spec["analytics"]
-    inputs = [["const", purchase_labels(raw, spec["query"], inp[1], inp[2])]
-              if inp[0] == "purchase_labels" else inp for inp in a["inputs"]]
+    made = getattr(gen, "INPUTS", {})
+    inputs = [["const", made[inp[0]](raw, spec["query"], *inp[1:])]
+              if inp[0] in made else inp for inp in a["inputs"]]
     return {**spec, "analytics": {**a, "inputs": inputs}}
 
 
-def build_pool(mix: dict, seed: int, raw: dict) -> dict[str, list[Entry]]:
-    """template -> its pool entries, drawn from ``seed`` and the data."""
+def build_pool(mix: dict, seed: int, raw: dict, gen
+               ) -> dict[str, list[Entry]]:
+    """template -> its pool entries, drawn from ``seed`` and the data made
+    by ``gen``, the configuration's generator module."""
     rng = np.random.default_rng([seed, 1])
+    kinds = {**PARAMS, **getattr(gen, "PARAMS", {})}
     pool: dict[str, list[Entry]] = {}
     for name, t in mix["templates"].items():
         spec = {k: v for k, v in t.items() if k != "params"}
         if "params" in t:
-            bound = _draw_params(t["params"], int(t["params"]["count"]), rng,
-                                 raw)
+            kind = t["params"]["kind"]
+            if kind not in kinds:
+                raise ValueError(f"unknown parameter kind {kind!r}")
+            bound = kinds[kind](t["params"], int(t["params"]["count"]), rng,
+                                raw)
         else:
             bound = [{}]
         pool[name] = []
         for i, p in enumerate(bound):
-            s = _labels(_bind(spec, p), raw)
+            s = _inputs(_bind(spec, p), raw, gen)
             pool[name].append(Entry(name, i, s, build_request(s)))
+    if len({len(pool[t]) for t in mix.get("session", ())}) > 1:
+        raise ValueError("a session's templates need pools of one size")
     return pool
 
 
-def rounds(pool: dict[str, list[Entry]], seed: int):
+def rounds(pool: dict[str, list[Entry]], seed: int,
+           session: list[str] | None = None):
     """Endless rounds, drawn as they are asked for: each holds every
-    template once in an order drawn from ``seed``."""
+    template once in an order drawn from ``seed``, or, for a ``session``,
+    its templates in its order on one pool index drawn from ``seed``."""
     rng = np.random.default_rng([seed, 2])
+    if session:
+        n = len(pool[session[0]])
+        while True:
+            i = int(rng.integers(n))
+            yield [pool[t][i] for t in session]
     names = sorted(pool)
     while True:
         order = rng.permutation(len(names))
         yield [pool[names[j]][int(rng.integers(len(pool[names[j]])))]
                for j in order]
+
+
+def warmup(pool: dict[str, list[Entry]],
+           session: list[str] | None = None) -> list[list[Entry]]:
+    """Set-up's pass over the pool, as rounds: every entry once, each its
+    own round, or for a ``session`` one whole session per pool index."""
+    if session:
+        return [[pool[t][i] for t in session]
+                for i in range(len(pool[session[0]]))]
+    return [[e] for entries in pool.values() for e in entries]
