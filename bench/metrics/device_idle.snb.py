@@ -1,0 +1,5 @@
+"""Share of the traced window in which no operation ran on the device."""
+
+
+def read(run):
+    return run.trace.idle_share() if run.trace is not None else None

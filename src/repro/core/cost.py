@@ -73,6 +73,56 @@ def cost_device_match(n_push_v: int, n_push_e: int, n_vertices: int,
     return tables + traverse + launches * COST_LAUNCH + est_result * n_deferred * COST_CPU
 
 
+def reach_levels(n_vertices: int, fanout: float, hi) -> int:
+    """BFS levels a reach runs: its upper bound, or for an unbounded one
+    the levels at which the fan-out alone covers every vertex, plus one."""
+    if fanout <= 1.0:
+        cover = max(int(n_vertices), 1)
+    else:
+        cover = int(np.ceil(np.log(max(n_vertices, 2)) / np.log(fanout))) + 1
+    return max(1, cover if hi is None else min(int(hi), cover))
+
+
+def reach_rows(n_label: float, fanout: float, lo: int, levels: int) -> float:
+    """Expected vertices at distance lo..levels from one start: fan-out
+    growth, capped by the end label's vertex count."""
+    within = sum(min(fanout ** d, n_label) for d in range(lo, levels + 1))
+    return float(min(within, n_label))
+
+
+def cost_reach(n_start: float, n_vertices: int, fanout: float, levels: int,
+               est_result: float, n_deferred: int) -> float:
+    """Host BFS reach: per start, every vertex reached before the last
+    level is expanded (its edges read once), capped by the graph."""
+    reached = min(sum(fanout ** d for d in range(levels)), float(n_vertices))
+    return (n_start * reached * max(fanout, 1.0) * COST_CPU
+            + est_result * n_deferred * COST_CPU)
+
+
+DENSE_BYTES_PER_CPU = 64.0   # adjacency bytes streamed per host CPU op
+
+
+def cost_device_reach(n_start: float, n_vertices: int, levels: int,
+                      est_result: float, n_deferred: int) -> float:
+    """Device reach: per start, one launch window and one pass over the
+    dense adjacency per level, the distance vector read back."""
+    side = float(dense_side(n_vertices))
+    per_start = (levels * side * side / DENSE_BYTES_PER_CPU
+                 + side + 2.0 * COST_LAUNCH)
+    return n_start * per_start * COST_CPU + est_result * n_deferred * COST_CPU
+
+
+def dense_side(n_vertices: int) -> int:
+    """The dense adjacency's side: vertices rounded up to 128 lanes, a whole
+    number of int8 tiles."""
+    return max(128, -(-int(n_vertices) // 128) * 128)
+
+
+def dense_adjacency_bytes(n_vertices: int) -> int:
+    """Bytes of a graph's dense int8 adjacency on the device."""
+    return dense_side(n_vertices) ** 2
+
+
 def should_push_range(g, tbl, pred) -> bool:
     """Cost-compare pushing a range predicate at the end vertex vs deferring
     it to the graph-relation (Fig. 6 end-vertex rule)."""

@@ -170,6 +170,7 @@ class GredoEngine:
                    "deltastore": _graph_writes,
                    "index": _index_counters,
                    "traversal_kernels": pattern_jit.metrics,
+                   "reach": pattern_jit.reach_metrics,
                    "shard": _shard_metrics}
         if self.observer is not None:
             sources["flight"] = self.observer.metrics

@@ -53,6 +53,10 @@ MAX_CACHE_ENTRIES = 50_000   # estimate-cache size backstop
 
 DEVICE_MATCH = True              # consider device access paths for patterns
 DEVICE_MAX_FRONTIER = float(1 << 18)   # skip device lowering past this peak
+# a reach lowers to the device when its graph's dense adjacency takes at
+# most this share of the device's memory (the rest: CSR snapshot, inputs,
+# outputs and whatever else the engine keeps there)
+REACH_DEVICE_SHARE = 0.5
 
 
 @dataclasses.dataclass
@@ -430,6 +434,9 @@ def _select_match_path(root: ph.PhysicalOp, db: Database, report: OptReport,
     patterns whose padded capacity would not fit the static-shape budget."""
     if not DEVICE_MATCH:
         return root
+    rp = _find_kind(root, ph.Reach)
+    if rp is not None:
+        return _select_reach_path(root, rp, db, report)
     mp = _find_kind(root, ph.MatchPattern)
     if (mp is None or mp.pplan is None or mp.children
             or not mp.pplan.pattern.edges or not mp.pplan.pattern.is_chain):
@@ -469,6 +476,33 @@ def _select_match_path(root: ph.PhysicalOp, db: Database, report: OptReport,
     report.add("access-path", f"{mp.graph}: pattern stays on host matcher "
                f"(cost {cost_host:.3g} <= device {c:.3g})")
     return root
+
+
+def _select_reach_path(root: ph.PhysicalOp, rp: ph.Reach, db: Database,
+                       report: OptReport) -> ph.PhysicalOp:
+    """Lower a reach to the device when its graph is settled and the dense
+    adjacency's bytes fit ``REACH_DEVICE_SHARE`` of the device's memory.
+    The chain's frontier gate does not apply: a reach's frontier never
+    exceeds |V|, and the adjacency's bytes are what the device must hold."""
+    g = db.graphs.get(rp.graph)
+    if g is None or type(rp) is not ph.Reach:
+        return root
+    if g.delta.has_pending():
+        report.add("access-path", f"{rp.graph}: reach stays on the host BFS "
+                   "(pending deltas)")
+        return root
+    from . import pattern_jit
+    need = cost_mod.dense_adjacency_bytes(g.n_vertices)
+    budget = int(REACH_DEVICE_SHARE * pattern_jit.device_memory_bytes())
+    if need > budget:
+        report.add("access-path", f"{rp.graph}: reach stays on the host BFS "
+                   f"(dense adjacency {need:.3g} B over the device budget "
+                   f"{budget:.3g} B)")
+        return root
+    dr = ph.DeviceReach(rp.graph, db.epoch_of(rp.graph), rp.pplan, budget)
+    report.add("access-path", f"{rp.graph}: device reach (dense adjacency "
+               f"{need:.3g} B within {budget:.3g} B)")
+    return _replace(root, {id(rp): dr})
 
 
 def _needed_columns(q, coll: str, residual: list) -> set:

@@ -215,6 +215,38 @@ def _as_mask(cand: Optional[tuple[str, np.ndarray]],
     return mask
 
 
+def start_nids_of(g: Graph, label: str,
+                  cand: Optional[tuple[str, np.ndarray]]) -> np.ndarray:
+    """The start frontier (Line 9) from a var's tagged candidate set: every
+    nid of the label, the index postings' nids (no full-label mask), or the
+    masked nids."""
+    if cand is None:
+        return g.label_nids(label)
+    if cand[0] == "rows":
+        return np.atleast_1d(g.nid_of(label, cand[1]))
+    return g.label_nids(label)[cand[1]]
+
+
+def nid_mask(g: Graph, label: str,
+             cand: Optional[tuple[str, np.ndarray]],
+             label_only: bool = False) -> Optional[np.ndarray]:
+    """A var's candidate set as a boolean mask over the nid space (with
+    pending delta vertices a label's nid set is its base block plus its
+    appended delta nids, in merged-table row order). An unconstrained var
+    gives None, or with ``label_only`` on a multi-label graph the label's
+    own mask (the hop filter the host matcher applies implicitly)."""
+    if cand is None:
+        if label_only and len(g.labels) > 1:
+            return np.asarray(g.vertex_label_code == g.label_code_of(label))
+        return None
+    full = np.zeros(g.n_vertices, dtype=bool)
+    if cand[0] == "mask":
+        full[g.label_nids(label)] = cand[1]
+    else:   # vid rows -> nids (delta rows included)
+        full[g.nid_of(label, cand[1])] = True
+    return full
+
+
 @dataclasses.dataclass
 class MatchState:
     """Prepared (pre-traversal) state of one pattern match: candidate/member
@@ -266,16 +298,7 @@ def prepare_match(g: Graph, plan: PatternPlan,
 
     def member_of(v: str) -> Optional[np.ndarray]:
         if v not in member:
-            c = cand[v]
-            if c is None:
-                member[v] = None
-            else:
-                full = np.zeros(g.n_vertices, dtype=bool)
-                if c[0] == "mask":
-                    full[g.label_nids(pattern.vertex(v).label)] = c[1]
-                else:   # vid rows -> nids (delta rows included)
-                    full[g.nid_of(pattern.vertex(v).label, c[1])] = True
-                member[v] = full
+            member[v] = nid_mask(g, pattern.vertex(v).label, cand[v])
         return member[v]
 
     edge_mask: dict[str, Optional[np.ndarray]] = {
@@ -284,15 +307,7 @@ def prepare_match(g: Graph, plan: PatternPlan,
 
     # initial frontier (Line 9): candidates of the first hop var
     v0 = hop_vars[0]
-    c0 = cand[v0]
-    if c0 is None:
-        start_nids = g.label_nids(pattern.vertex(v0).label)
-    elif c0[0] == "rows":
-        # frontier seeded straight from index postings — no full-label mask
-        start_nids = np.atleast_1d(g.nid_of(pattern.vertex(v0).label, c0[1]))
-    else:
-        v0_nids = g.label_nids(pattern.vertex(v0).label)
-        start_nids = v0_nids[c0[1]]
+    start_nids = start_nids_of(g, pattern.vertex(v0).label, cand[v0])
 
     return MatchState(plan, chain_vars, edge_vars, hop_vars, hop_edges,
                       member_of, edge_mask, start_nids)
@@ -386,8 +401,37 @@ def apply_deferred(g: Graph, pattern: Pattern, rel: Table, deferred: dict) -> Ta
 
 
 # ---------------------------------------------------------------------------
-# Shortest-path search (topology-only GraphAM; powers M2Bench G6-G8)
+# Shortest-path search (topology-only GraphAM; powers M2Bench G6-G8 and the
+# reach of a quantified hop)
 # ---------------------------------------------------------------------------
+
+
+def bfs_distances(g: Graph, start: int, max_hops: Optional[int] = None,
+                  reverse: bool = False,
+                  want: Optional[np.ndarray] = None) -> tuple[np.ndarray, int]:
+    """Level-synchronous BFS from one nid over base CSR ⊕ delta (no record
+    access). Returns ``(dist, levels)``: the hop distance of every nid (-1
+    where not reached) and the levels expanded. Stops after ``max_hops``
+    levels (None: unbounded), at an empty frontier, or once every nid of
+    the ``want`` mask has its distance (a settled distance never changes).
+    ``reverse`` walks edges backwards: distances *to* ``start``."""
+    dist = np.full(g.n_vertices, -1, dtype=np.int32)
+    dist[start] = 0
+    frontier = np.array([start])
+    level = 0
+    while max_hops is None or level < max_hops:
+        if want is not None and not (want & (dist < 0)).any():
+            break
+        _, nxt, _ = g.expand(frontier, reverse=reverse)
+        nxt = np.unique(nxt)
+        nxt = nxt[dist[nxt] < 0]
+        if len(nxt) == 0:
+            break
+        level += 1
+        dist[nxt] = level
+        frontier = nxt
+        traversal.COUNTERS.cpu_ops += len(nxt)
+    return dist, level
 
 
 def shortest_path_lengths(g: Graph, src_nids: np.ndarray, dst_nids: np.ndarray,
@@ -401,17 +445,79 @@ def shortest_path_lengths(g: Graph, src_nids: np.ndarray, dst_nids: np.ndarray,
     # group by src to share BFS frontiers
     uniq, inv = np.unique(src_nids, return_inverse=True)
     for i, s in enumerate(uniq):
-        dist = np.full(g.n_vertices, -1, dtype=np.int32)
-        dist[s] = 0
-        frontier = np.array([s])
-        for h in range(1, max_hops + 1):
-            _, nxt, _ = g.expand(frontier)
-            nxt = np.unique(nxt)
-            nxt = nxt[dist[nxt] < 0]
-            if len(nxt) == 0:
-                break
-            dist[nxt] = h
-            frontier = nxt
+        dist, _ = bfs_distances(g, s, max_hops)
         sel = inv == i
         out[sel] = dist[dst_nids[sel]]
     return out
+
+
+@dataclasses.dataclass
+class ReachState:
+    """A reach's plan lowered against the graph: the traversal's start
+    nids, the end var's member mask over the nid space (pushed predicates
+    and the label folded in; None = every vertex), the distance bounds and
+    the direction. Shared by the host BFS and the device program."""
+
+    start_var: str
+    end_var: str
+    edge_var: str
+    start_nids: np.ndarray
+    member: Optional[np.ndarray]
+    lo: int
+    hi: Optional[int]
+    reverse: bool
+
+
+def prepare_reach(g: Graph, plan: PatternPlan) -> ReachState:
+    """Candidate sets of a reach, as ``prepare_match`` makes them for a
+    chain: the start side's pushed predicates define the sources, the end
+    side's filter the reached vertices. ``plan.reverse`` starts from the
+    pattern's ``dst`` and walks edges backwards, which gives the same
+    distances d(src, dst)."""
+    pattern = plan.pattern
+    edge = pattern.reach
+    start_var, end_var = ((edge.dst, edge.src) if plan.reverse
+                          else (edge.src, edge.dst))
+    cand = {v: _candidate_set(g, pattern, v, plan.pushed.get(v, []))
+            for v in (start_var, end_var)}
+    start_nids = start_nids_of(g, pattern.vertex(start_var).label,
+                               cand[start_var])
+    member = nid_mask(g, pattern.vertex(end_var).label, cand[end_var],
+                      label_only=True)
+    lo, hi = edge.quantifier
+    return ReachState(start_var, end_var, edge.var, np.asarray(start_nids),
+                      member, lo, hi, bool(plan.reverse))
+
+
+def reach_relation(g: Graph, plan: PatternPlan, st: ReachState,
+                   starts: list, ends: list, dists: list) -> Table:
+    """The graph-relation of a reach from per-start nid and distance
+    arrays: vertex vars hold vids, the edge var holds the distance;
+    deferred predicates applied."""
+    def cat(parts, dtype):
+        return (np.concatenate(parts) if parts
+                else np.zeros(0, dtype=dtype)).astype(dtype)
+    cols = {st.start_var: g.vids_of(cat(starts, np.int64)),
+            st.end_var: g.vids_of(cat(ends, np.int64)),
+            st.edge_var: cat(dists, np.int64)}
+    rel = Table(f"reach:{plan.pattern.graph}", cols)
+    return apply_deferred(g, plan.pattern, rel, plan.deferred)
+
+
+def reach(g: Graph, plan: PatternPlan) -> Table:
+    """Execute a one-hop reach on the host: one BFS per start vertex, one
+    row per vertex whose distance lies in the quantifier's bounds and that
+    passes the end side's predicates. The host form of the reach operator:
+    it reads base CSR ⊕ delta, so it serves graphs with pending writes."""
+    st = prepare_reach(g, plan)
+    starts, ends, dists = [], [], []
+    for s in st.start_nids:
+        dist, _ = bfs_distances(g, int(s), st.hi, st.reverse, st.member)
+        keep = dist >= st.lo
+        if st.member is not None:
+            keep &= st.member
+        ids = np.flatnonzero(keep)
+        starts.append(np.full(len(ids), s, dtype=np.int64))
+        ends.append(ids)
+        dists.append(dist[ids])
+    return reach_relation(g, plan, st, starts, ends, dists)

@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import weakref
 from typing import Optional
 
 import jax
@@ -37,6 +38,7 @@ import numpy as np
 
 from repro.kernels.traversal import ops as kernel_ops
 
+from . import cost as cost_mod
 from . import pattern as pattern_mod
 from .storage import Graph, Table
 
@@ -263,23 +265,8 @@ def prepare_chain(g: Graph, pplan, zone: bool = True) -> Optional[dict]:
                                           pplan.pushed.get(v, []))
             for v in chain_vars}
 
-    def member_of(v: str) -> Optional[np.ndarray]:
-        c = cand[v]
-        if c is None:
-            if len(g.labels) > 1:
-                # label constraint (host matcher's implicit hop filter)
-                return np.asarray(
-                    g.vertex_label_code
-                    == g.label_code_of(pattern.vertex(v).label))
-            return None
-        full = np.zeros(g.n_vertices, dtype=bool)
-        if c[0] == "mask":
-            full[g.label_nids(pattern.vertex(v).label)] = c[1]
-        else:       # vid rows -> nids
-            full[g.nid_of(pattern.vertex(v).label, c[1])] = True
-        return full
-
-    members = [member_of(v) for v in hop_vars[1:]]
+    members = [pattern_mod.nid_mask(g, pattern.vertex(v).label, cand[v],
+                                    label_only=True) for v in hop_vars[1:]]
 
     im = getattr(g, "_index_manager", None)
     chunk = 0
@@ -314,14 +301,8 @@ def prepare_chain(g: Graph, pplan, zone: bool = True) -> Optional[dict]:
         chunk_alives.append(alive)
 
     v0 = hop_vars[0]
-    c0 = cand[v0]
-    if c0 is None:
-        start_nids = g.label_nids(pattern.vertex(v0).label)
-    elif c0[0] == "rows":
-        start_nids = np.atleast_1d(g.nid_of(pattern.vertex(v0).label, c0[1]))
-    else:
-        v0_nids = g.label_nids(pattern.vertex(v0).label)
-        start_nids = v0_nids[c0[1]]
+    start_nids = pattern_mod.start_nids_of(g, pattern.vertex(v0).label,
+                                           cand[v0])
 
     from .cost import ZONE_CHUNK
     return {"start_nids": start_nids, "members": members,
@@ -439,3 +420,124 @@ def device_match(g: Graph, pplan, *, flavor: str = "chain",
         trace.phase(None)
     return rel, {"flavor": flavor, "hops": hops, "capacity": cap,
                  "launches": launches}
+
+
+# ---------------------------------------------------------------------------
+# Reach: one-hop shortest-distance search on the device
+# ---------------------------------------------------------------------------
+
+# Graphs that hold a dense adjacency on the device (the gauge reads them).
+_ADJACENCY_GRAPHS: "weakref.WeakSet[Graph]" = weakref.WeakSet()
+DEFAULT_DEVICE_BYTES = 16 * 10**9   # one TPU v5e, where the backend reports none
+
+
+@dataclasses.dataclass
+class _ReachCounters:
+    launches: int = 0           # reach programs run (one per start vertex)
+    levels: int = 0             # BFS levels they ran
+    reached: int = 0            # vertices they reached, starts not counted
+
+    def metrics(self) -> dict:
+        return {"launches": self.launches, "levels": self.levels,
+                "reached": self.reached,
+                "adjacency_bytes": sum(
+                    held[1].nbytes for g in list(_ADJACENCY_GRAPHS)
+                    if (held := getattr(g, "_dense_adjacency", None)))}
+
+
+REACH = _ReachCounters()
+
+
+def reach_metrics() -> dict:
+    """Telemetry registry source ``reach``: cumulative launch, level and
+    reached-vertex counters, and the gauge ``adjacency_bytes``, the bytes of
+    the dense adjacencies held on the device."""
+    return REACH.metrics()
+
+
+@functools.lru_cache(maxsize=1)
+def device_memory_bytes() -> int:
+    """The default device's memory as its backend reports it
+    (``bytes_limit``), else ``DEFAULT_DEVICE_BYTES``; read once."""
+    stats = jax.devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit") or DEFAULT_DEVICE_BYTES)
+
+
+def _symmetric(g: Graph) -> bool:
+    """Whether the graph's edge multiset equals its transpose (an
+    undirected graph stored in both directions): the forward and reverse
+    CSRs then hold the same (row, col) pairs."""
+    keys = []
+    for csr in (g.fwd, g.rev):
+        rows = np.repeat(np.arange(len(csr.row_ptr) - 1, dtype=np.int64),
+                         np.diff(csr.row_ptr))
+        keys.append(np.sort(rows * g.n_vertices + csr.col_idx))
+    return bool(np.array_equal(keys[0], keys[1]))
+
+
+def get_adjacency(g: Graph, matcher: DevicePatternMatcher
+                  ) -> tuple[jax.Array, bool]:
+    """The graph's dense int8 adjacency on the device and whether it is
+    symmetric, built from the (refreshed) matcher's CSR snapshot at the
+    graph's first reach and again after a compaction changed the
+    snapshot."""
+    held = getattr(g, "_dense_adjacency", None)
+    if held is None or held[0] != matcher.epoch:
+        g._dense_adjacency = None       # free the old one before the build
+        adj = kernel_ops.dense_adjacency(
+            matcher.row_ptr, matcher.col_idx,
+            n_pad=cost_mod.dense_side(g.n_vertices))
+        g._dense_adjacency = held = (matcher.epoch, adj, _symmetric(g))
+        _ADJACENCY_GRAPHS.add(g)
+    return held[1], held[2]
+
+
+def device_reach(g: Graph, pplan, *, trace=None):
+    """Execute a reach PatternPlan on the device and build the same
+    graph-relation as ``pattern.reach``. Returns (rel, counts): the run's
+    ``launches``, ``levels`` (passes over the adjacency) and ``reached``,
+    for the operator's span. Where the end side is one vertex and the graph
+    is undirected, each launch searches from both ends of the pair at once,
+    which reaches a distance d in ceil(d/2) passes instead of d. With a
+    ``trace``, records the phases ``lower`` (the snapshot refresh and the
+    plan lowered to start nids and the end mask), ``stage`` (the dense
+    adjacency, built at the graph's first reach, and the mask to the
+    device), ``launch`` (one program per start vertex, through its sync) and
+    ``readback`` (distances to the host and the relation). Raises
+    ``StaleSnapshotError`` on pending deltas; callers degrade to the host."""
+    if trace is not None:
+        trace.phase("lower")
+    matcher = get_matcher(g)
+    matcher.refresh()
+    st = pattern_mod.prepare_reach(g, pplan)
+    if trace is not None:
+        trace.phase("stage")
+    adj, symmetric = get_adjacency(g, matcher)
+    target = None
+    if symmetric and st.member is not None:
+        ends = np.flatnonzero(st.member)
+        target = int(ends[0]) if len(ends) == 1 else None
+    member = kernel_ops.stage_reach(st.member, g.n_vertices, adj.shape[0])
+    starts, ends, dists = [], [], []
+    levels = reached = 0
+    for s in st.start_nids:
+        if trace is not None:
+            trace.phase("launch")
+        out = kernel_ops.launch_reach(adj, int(s), member, st.lo, st.hi,
+                                      transpose=st.reverse, target=target)
+        if trace is not None:
+            trace.phase("readback")
+        ids, d, lv, rc = kernel_ops.read_reach(out, g.n_vertices)
+        starts.append(np.full(len(ids), s, dtype=np.int64))
+        ends.append(ids)
+        dists.append(d)
+        levels += lv
+        reached += rc
+    launches = len(st.start_nids)
+    REACH.launches += launches
+    REACH.levels += levels
+    REACH.reached += reached
+    rel = pattern_mod.reach_relation(g, pplan, st, starts, ends, dists)
+    if trace is not None:
+        trace.phase(None)
+    return rel, {"launches": launches, "levels": levels, "reached": reached}

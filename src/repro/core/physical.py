@@ -447,6 +447,72 @@ class DeviceMatchPattern(PhysicalOp):
                 f"via {self.access}{cap}]")
 
 
+class Reach(PhysicalOp):
+    """A one-hop reach (a quantified hop): one row per (start, end) pair
+    whose shortest-path distance lies in the quantifier's bounds, the edge
+    var bound to that distance. The host form, a BFS per start vertex over
+    base CSR ⊕ delta (``pattern.reach``); it serves graphs with pending
+    writes and reaches the device form does not fit."""
+    kind = "Reach"
+
+    def __init__(self, graph: str, epoch: int, pplan):
+        super().__init__()
+        self.graph = graph
+        self.epoch = epoch
+        self.pplan = pplan
+
+    def params(self):
+        return (self.graph, self.epoch, _pattern_sig(self.pplan.pattern),
+                _pplan_sig(self.pplan))
+
+    def run(self, ctx, *inputs):
+        return pattern_mod.reach(ctx.db.graphs[self.graph], self.pplan)
+
+    def describe(self):
+        p = self.pplan
+        edge = p.pattern.reach
+        hops = "?" if edge is None else "{}..{}".format(
+            edge.quantifier[0], edge.quantifier[1] or "")
+        pushed = ",".join(f"{v}:{len(ps)}"
+                          for v, ps in sorted(p.pushed.items())) or "-"
+        return (f"{self.kind}[{self.graph} dir={'rev' if p.reverse else 'fwd'}"
+                f" hops={hops} pushed={pushed}]")
+
+
+class DeviceReach(Reach):
+    """The reach on the accelerator: one level-synchronous BFS program per
+    start vertex over the graph's dense int8 adjacency, held on the device
+    from the graph's first reach (``pattern_jit.device_reach``). The
+    optimizer lowers a reach here when the graph is settled and the
+    adjacency's bytes fit ``budget``. Falls back to the host BFS if the
+    graph has grown pending deltas since planning."""
+    kind = "DeviceReach"
+
+    def __init__(self, graph: str, epoch: int, pplan, budget: int):
+        super().__init__(graph, epoch, pplan)
+        self.budget = int(budget)
+        self.access = "device-reach"
+        # the counts of the last run (launches, levels, reached); merged
+        # into the telemetry span
+        self.last_kernel_args: Optional[dict] = None
+
+    def params(self):
+        return super().params() + (self.budget,)
+
+    def run(self, ctx, *inputs):
+        from . import pattern_jit
+        g = ctx.db.graphs[self.graph]
+        if g.delta.has_pending():
+            self.access = "host-fallback"
+            return pattern_mod.reach(g, self.pplan)
+        rel, self.last_kernel_args = pattern_jit.device_reach(
+            g, self.pplan, trace=ctx.trace)
+        return rel
+
+    def describe(self):
+        return f"{super().describe()} via {self.access}"
+
+
 class TableJoinMatch(PhysicalOp):
     """GredoDB-S ablation: the pattern as k-way edge-table equi-joins (the
     TBS strategy §2.2) with deferred predicates evaluated post-hoc."""
@@ -550,11 +616,15 @@ class GraphProject(PhysicalOp):
     def run(self, ctx, rel: Table):
         g = ctx.db.graphs[self.graph]
         edge_vars = {e.var for e in self.pattern.edges}
+        reach = self.pattern.reach
         cols: dict[str, np.ndarray] = {}
         for var in self.keep:
             if var not in rel.columns:
                 continue
             ids = np.asarray(rel.col(var))
+            if reach is not None and var == reach.var:
+                cols[f"{var}.hops"] = ids   # a reach binds the distance
+                continue
             cols[f"{var}.__id"] = ids
             tbl = (g.edges if var in edge_vars
                    else g.vertex_tables[self.pattern.vertex(var).label])
@@ -995,7 +1065,11 @@ def build_gcdi(db: Database, p, mode: str = "gredo") -> PhysicalOp:
         gep = db.epoch_of(gname)
         all_vars = ({v.var for v in pattern.vertices}
                     | {e.var for e in pattern.edges})
-        if mode == "single":
+        if pattern.reach is not None:
+            # one operator in every mode: the device form is the optimizer's
+            graph_node = Reach(gname, gep, p.pattern_plan)
+            vars_in_rel = all_vars
+        elif mode == "single":
             deferred = p.pattern_plan.deferred if p.pattern_plan else {}
             graph_node = TableJoinMatch(gname, gep, pattern, deferred)
             vars_in_rel = all_vars
@@ -1023,6 +1097,9 @@ def build_gcdi(db: Database, p, mode: str = "gredo") -> PhysicalOp:
                                   wanted, graph_node)
         graph_cols: set[str] = set()
         for var in sorted(keep):
+            if pattern.reach is not None and var == pattern.reach.var:
+                graph_cols.add(f"{var}.hops")
+                continue
             graph_cols.add(f"{var}.__id")
             for attr in dict.fromkeys(wanted.get(var, [])):
                 graph_cols.add(f"{var}.{attr}")
@@ -1419,6 +1496,32 @@ def estimate(root: PhysicalOp, db: Database,
                 gm_fanout, rows,
                 sum(len(ps) for ps in p.deferred.values()),
                 zone_frac=zf, per_hop_sync=(n.access == "device-jit"))
+        elif isinstance(n, Reach):
+            # per start: the vertices within the quantifier's bounds, from
+            # the per-hop fan-out capped by the end label; the host BFS
+            # expands every vertex it reaches, the device program streams
+            # the dense adjacency once per level
+            g = db.graphs[n.graph]
+            p = n.pplan
+            edge = p.pattern.reach
+            start, end = ((edge.dst, edge.src) if p.reverse
+                          else (edge.src, edge.dst))
+            stbl = g.vertex_tables[p.pattern.vertex(start).label]
+            etbl = g.vertex_tables[p.pattern.vertex(end).label]
+            n_start = stbl.nrows * sel(stbl, p.pushed.get(start, []))
+            lo, hi = edge.quantifier
+            fan = g.hop_expansion(reverse=p.reverse,
+                                  label=p.pattern.vertex(start).label)
+            levels = cost_mod.reach_levels(g.n_vertices, fan, hi)
+            rows = (n_start * sel(etbl, p.pushed.get(end, []))
+                    * cost_mod.reach_rows(etbl.nrows, fan, lo, levels))
+            n_def = sum(len(ps) for ps in p.deferred.values())
+            if isinstance(n, DeviceReach):
+                cost = cost_mod.cost_device_reach(n_start, g.n_vertices,
+                                                  levels, rows, n_def)
+            else:
+                cost = cost_mod.cost_reach(n_start, g.n_vertices, fan,
+                                           levels, rows, n_def)
         elif isinstance(n, TableJoinMatch):
             g = db.graphs[n.graph]
             hops = len(n.pattern.edges)

@@ -13,6 +13,10 @@ Four mechanisms, as in the paper:
      projection trimming (drop graph-projection columns never referenced).
   4. *Query-aware traversal pruning* — carried by PatternPlan.fetch_vars:
      unreferenced, predicate-free pattern vars never fetch records.
+
+A pattern whose one hop carries a quantifier is a *reach* (``Pattern.reach``):
+it is planned as a two-vertex chain (the start side by the same Fig. 6
+rules), never trimmed, and its joins stay post-match.
 """
 from __future__ import annotations
 
@@ -84,6 +88,11 @@ def plan(db: Database, q: Query, enable_opt: bool = True,
         else:
             residual.append(p)
 
+    reach = pattern.reach if pattern else None
+    if reach is not None and reach.var in phi:
+        raise ValueError(f"a reach's edge var {reach.var!r} binds no edge: "
+                         "bound its distance by the hop's quantifier")
+
     # --- mechanism 1b: replicate predicates across models via join equality ---
     if enable_opt and pattern:
         for jp in q.joins:
@@ -107,7 +116,7 @@ def plan(db: Database, q: Query, enable_opt: bool = True,
 
     # --- mechanism 3a: match trimming ---
     match_trim = None
-    if enable_opt and pattern:
+    if enable_opt and pattern and reach is None:
         referenced = _referenced_vars(q, pattern_vars)
         if not pattern.edges:
             match_trim = "vertex_scan"
@@ -133,8 +142,9 @@ def plan(db: Database, q: Query, enable_opt: bool = True,
     # table/document collection to a pattern vertex. The cost-based siding
     # (graph-side mask vs. table-side reduce vs. post-match join) is a
     # physical rewrite, made by repro.core.optimizer against live statistics.
+    # (a reach keeps its joins post-match: its operator takes no masks)
     semi_join_idx: set[int] = set()
-    if enable_opt and pattern and not match_trim:
+    if enable_opt and pattern and not match_trim and reach is None:
         for i, jp in enumerate(q.joins):
             if _graph_join_side(q, pattern_vars, jp) is not None:
                 semi_join_idx.add(i)

@@ -100,6 +100,9 @@ class PatternEdge:
     label: str
     src: str            # source vertex var
     dst: str            # target vertex var
+    # a quantified hop ``{min, max}`` (max None = unbounded): the edge var
+    # then binds no edge but the shortest-path distance, ``<var>.hops``
+    quantifier: Optional[tuple[int, Optional[int]]] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -126,7 +129,19 @@ class Pattern:
         physical plan's node signatures embed (§6.4 structural matching)."""
         return (self.graph,
                 tuple((v.var, v.label) for v in self.vertices),
-                tuple((e.var, e.label, e.src, e.dst) for e in self.edges))
+                tuple((e.var, e.label, e.src, e.dst)
+                      + ((e.quantifier,) if e.quantifier else ())
+                      for e in self.edges))
+
+    @property
+    def reach(self) -> Optional[PatternEdge]:
+        """The quantified edge of a one-hop reach, else None. A reach binds
+        one row per vertex ``dst`` whose shortest-path distance d from
+        ``src`` satisfies min <= d <= max (SQL/PGQ's ``ANY SHORTEST`` with a
+        bounded quantifier, projected to its endpoints)."""
+        if len(self.edges) == 1 and self.edges[0].quantifier is not None:
+            return self.edges[0]
+        return None
 
     @property
     def is_chain(self) -> bool:
@@ -140,21 +155,42 @@ class Pattern:
         return True
 
 
-def chain_pattern(graph: str, *hops: tuple[str, str, str, str, str]) -> Pattern:
+def chain_pattern(graph: str, *hops: tuple) -> Pattern:
     """Build a chain pattern from (src_var, src_label, edge_label, dst_var,
     dst_label) hops, e.g. ``chain_pattern("Interested_in",
-    ("p","Persons","Interested_in","t","Tags"))``."""
+    ("p","Persons","Interested_in","t","Tags"))``. A hop may add a
+    quantifier, ``(..., dst_label, min, max)`` with ``max`` None for
+    unbounded: the pattern is then a one-hop reach (``Pattern.reach``), and
+    must have no other hop."""
     vertices: list[PatternVertex] = []
     edges: list[PatternEdge] = []
     seen = {}
-    for i, (sv, sl, el, dv, dl) in enumerate(hops):
+    for i, hop in enumerate(hops):
+        if len(hop) not in (5, 7):
+            raise ValueError(f"hop {hop!r}: expected 5 fields, or 7 with a "
+                             "quantifier (min, max)")
+        sv, sl, el, dv, dl = hop[:5]
+        quant = None
+        if len(hop) == 7:
+            lo, hi = hop[5], hop[6]
+            if (int(lo) != lo or lo < 0 or hi is not None
+                    and (int(hi) != hi or hi < lo)):
+                raise ValueError(f"hop {hop!r}: quantifier needs "
+                                 "0 <= min <= max (max None: unbounded)")
+            if len(hops) != 1:
+                raise ValueError("a quantified hop is a one-hop reach: the "
+                                 "pattern may have no other hop")
+            if sv == dv:
+                raise ValueError(f"hop {hop!r}: a reach needs two vertex "
+                                 "vars")
+            quant = (int(lo), None if hi is None else int(hi))
         if sv not in seen:
             seen[sv] = PatternVertex(sv, sl)
             vertices.append(seen[sv])
         if dv not in seen:
             seen[dv] = PatternVertex(dv, dl)
             vertices.append(seen[dv])
-        edges.append(PatternEdge(f"e{i}", el, sv, dv))
+        edges.append(PatternEdge(f"e{i}", el, sv, dv, quant))
     return Pattern(graph, tuple(vertices), tuple(edges))
 
 

@@ -18,7 +18,8 @@ them behind three primitives, all off-by-default and designed so the
   Under the query root the engine adds two phase spans, ``plan`` (before
   the executor) and ``finish`` (its own bookkeeping), and the operators
   that set the device's pace add phase spans of their own
-  (``DeviceMatchPattern``: ``lower``/``stage``/``launch``/``readback``;
+  (``DeviceMatchPattern`` and ``DeviceReach``:
+  ``lower``/``stage``/``launch``/``readback``;
   the matrix builders: ``build``/``transfer``). Phase spans (category
   ``"phase"``) are part of their parent's own time, not operators.
   Each span is also a ``jax.profiler.TraceAnnotation`` named
@@ -692,7 +693,7 @@ class QErrorMonitor:
 # ---------------------------------------------------------------------------
 
 GCDA_KINDS = ("Rel2Matrix", "RandomAccessMatrix", "MatMul", "Similarity",
-              "Regression", "Const", "DeviceMatchPattern")
+              "Regression", "Const", "DeviceMatchPattern", "DeviceReach")
 
 
 def fence(value) -> float:

@@ -31,8 +31,10 @@ V-SHARD   shard invariants: ``shards`` stamps only on shardable kinds and
           only as an EquiJoin build side
 V-DEV     device-lowering preconditions: ``DeviceMatchPattern`` only on
           mask-free chain patterns with edges, capacity ≥ the statically
-          derivable padded frontier bound; pending deltas (host fallback at
-          runtime) are WARNs
+          derivable padded frontier bound; ``DeviceReach`` only on a
+          one-hop reach, mask-free, its dense adjacency within the stated
+          device budget; pending deltas (host fallback at runtime) are
+          WARNs
 V-ANN     ``out_cols`` annotations agree with the inferred schema (stale
           annotations mislead column pruning and the optimizer)
 V-EQ      rewrite equivalence: optimizer/shard output schemas ≡ naive-plan
@@ -519,6 +521,47 @@ class _Inference:
                              f"frontier bound {need} (peak≈{peak:.3g})")
         return self._pattern_vars(pattern)
 
+    def _infer_Reach(self, node, *mask_schemas):
+        self._check_graph_epoch(node)
+        g = self._graph(node)
+        if node.pplan is None:
+            self.err("V-COL", node, "has no pattern plan")
+            return {}
+        pattern = node.pplan.pattern
+        if pattern.reach is None:
+            self.err("V-COL", node, "pattern is not a one-hop reach")
+            return self._pattern_vars(pattern)
+        evar = pattern.reach.var
+        for what, m in (("pushed", node.pplan.pushed),
+                        ("deferred", node.pplan.deferred)):
+            if m.get(evar):
+                self.err("V-COL", node, f"{what} predicates on the reach's "
+                         f"edge var {evar!r}, which binds no edge")
+            elif g is not None:
+                self._check_pattern_preds(node, g, pattern, m, what)
+        return self._pattern_vars(pattern)
+
+    def _infer_DeviceReach(self, node, *mask_schemas):
+        out = self._infer_Reach(node)
+        g = self._graph(node)
+        if node.children:
+            self.err("V-DEV", node,
+                     f"has {len(node.children)} mask child(ren) — a device "
+                     f"reach takes none")
+        if node.pplan is not None and node.pplan.pattern.reach is None:
+            self.err("V-DEV", node, "pattern is not a one-hop reach")
+        if g is not None:
+            if g.delta.has_pending():
+                self.warn("V-DEV", node,
+                          "graph has pending deltas — runtime will fall "
+                          "back to the host BFS")
+            need = cost.dense_adjacency_bytes(g.n_vertices)
+            if need > node.budget:
+                self.err("V-DEV", node,
+                         f"dense adjacency {need} B over the device budget "
+                         f"{node.budget} B")
+        return out
+
     def _infer_TableJoinMatch(self, node):
         self._check_graph_epoch(node)
         g = self._graph(node)
@@ -559,12 +602,22 @@ class _Inference:
         if g is None:
             return {}
         edge_vars = {e.var for e in node.pattern.edges}
+        reach = node.pattern.reach
         out: dict = {}
         for var in node.keep:
             if var not in child:
                 self.warn("V-COL", node,
                           f"keep var {var!r} not bound by the child match "
                           f"(silently skipped at runtime)")
+                continue
+            if reach is not None and var == reach.var:
+                # a reach's edge var binds its distance, its one property
+                out[f"{var}.hops"] = child[var]
+                for attr in node.wanted.get(var, []):
+                    if attr != "hops":
+                        self.err("V-COL", node,
+                                 f"projected attribute {var}.{attr}: a "
+                                 f"reach's edge var has only 'hops'")
                 continue
             out[f"{var}.__id"] = child[var]
             tbl = (g.edges if var in edge_vars

@@ -232,3 +232,110 @@ def batched_traverse(row_ptr, col_idx, edge_id, n_vertices: int,
         return None, None, None, False
     return ([np.asarray(c) for c in vcols], [np.asarray(c) for c in ecols],
             np.asarray(counts), True)
+
+
+# ---------------------------------------------------------------------------
+# Reach: a level-synchronous BFS over a dense 0/1 adjacency
+# ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.jit, static_argnames=("n_pad",))
+def dense_adjacency(row_ptr, col_idx, *, n_pad: int):
+    """The graph's (n_pad, n_pad) int8 adjacency, A[u, v] = 1 for an edge
+    u -> v, built on the device from its CSR by one scatter. Each edge's
+    source row is the number of row starts at or before its slot, a scatter
+    of ones at the row starts and a running sum (empty rows share a start,
+    so the scatter adds)."""
+    n_edges = col_idx.shape[0]
+    starts = jnp.zeros((n_edges,), jnp.int32).at[row_ptr[1:-1]].add(
+        1, mode="drop")
+    rows = jnp.cumsum(starts)
+    return jnp.zeros((n_pad, n_pad), jnp.int8).at[rows, col_idx].set(
+        1, mode="drop")
+
+
+@functools.partial(jax.jit, static_argnames=("transpose",))
+def reach_program(adj, ends, member, lo, hi, *, transpose: bool):
+    """BFS from each of the k vertices in ``ends`` at once, as a
+    ``lax.while_loop`` over levels: each level multiplies the (k, n) int8
+    frontier block with the adjacency in one pass over it, int32
+    accumulator, so exact (``transpose`` walks edges backwards).
+
+    k = 1 searches from ``ends[0]`` and stops at level ``hi``, at an empty
+    frontier, or once every vertex of ``member`` has its distance; a vertex's
+    result is its distance. k = 2 searches an undirected (symmetric)
+    adjacency from both ends of a pair: after l levels both balls have
+    radius l, and once they meet, min(d_0 + d_1) over the vertices both
+    reached is the distance (a shortest path's vertex min(d, l) levels from
+    the first end lies in both). It stops when they meet, when either side
+    stops growing (no path), or when a path could no longer be at most
+    ``hi`` long; the result, at ``ends[1]``, is that distance.
+
+    Returns the result of each ``member`` vertex with lo <= d <= hi (-1
+    elsewhere), the levels run, and the vertices the searches reached (the
+    ends not counted)."""
+    k = ends.shape[0]
+    idx = jnp.arange(adj.shape[0], dtype=jnp.int32)
+    front = idx[None, :] == ends[:, None]
+    dims = (((1,), (1 if transpose else 0,)), ((), ()))
+    none = jnp.int32(2**30)
+
+    def met(d):
+        both = jnp.all(d >= 0, axis=0)
+        return jnp.min(jnp.where(both, jnp.sum(d, axis=0), none))
+
+    def cond(s):
+        level, _, d, grew = s
+        if k == 1:
+            return grew & (level < hi) & jnp.any(member & (d[0] < 0))
+        return grew & (met(d) == none) & (2 * level + 1 <= hi)
+
+    def body(s):
+        level, front, d, _ = s
+        hits = jax.lax.dot_general(front, adj, dims,
+                                   preferred_element_type=jnp.int32)
+        new = (hits > 0) & (d < 0)
+        level = level + 1
+        return level, new.astype(jnp.int8), jnp.where(new, level, d), \
+            jnp.all(jnp.any(new, axis=1))
+
+    levels, _, d, _ = jax.lax.while_loop(
+        cond, body, (jnp.int32(0), front.astype(jnp.int8),
+                     jnp.where(front, 0, -1).astype(jnp.int32),
+                     jnp.bool_(True)))
+    dist = d[0] if k == 1 else jnp.where(idx == ends[1], met(d), -1)
+    keep = member & (dist >= lo) & (dist <= hi)
+    return jnp.where(keep, dist, -1), levels, jnp.sum(d > 0, dtype=jnp.int32)
+
+
+def stage_reach(member, n_vertices: int, n_pad: int):
+    """The end side's member mask as a device (n_pad,) bool vector (None:
+    every real vertex)."""
+    m = np.zeros(n_pad, dtype=bool)
+    if member is None:
+        m[:n_vertices] = True
+    else:
+        m[:n_vertices] = member
+    return jnp.asarray(m)
+
+
+def launch_reach(adj, start: int, member, lo: int, hi, *,
+                 transpose: bool = False, target: int | None = None):
+    """Run the reach program from ``start``, or with a ``target`` from both
+    ends of the pair; its one host sync reads the levels it ran. ``hi``
+    None is unbounded. Returns the device's ``(dist, levels, reached)``,
+    levels as an int."""
+    big = adj.shape[0]
+    ends = [start] if target is None else [start, target]
+    dist, levels, reached = reach_program(
+        adj, jnp.asarray(ends, jnp.int32), member, jnp.int32(lo),
+        jnp.int32(big if hi is None else min(hi, big)), transpose=transpose)
+    return dist, int(levels), reached
+
+
+def read_reach(launched, n_vertices: int) -> tuple:
+    """``(nids, dists, levels, reached)`` of a launch, as host values."""
+    dist, levels, reached = launched
+    d = np.asarray(dist)[:n_vertices]
+    ids = np.flatnonzero(d >= 0)
+    return ids, d[ids], levels, int(reached)

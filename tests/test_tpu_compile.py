@@ -106,3 +106,32 @@ def test_device_chain_program_compiles_for_v5e(chain, one_chip):
     if chain in MAX_GATHERS:
         assert len(re.findall(r"\sgather\(", text)) <= MAX_GATHERS[chain]
     assert _fits(compiled)
+
+
+# LDBC SNB SF10's knows graph: 65,645 persons (65,664 padded to 128 lanes),
+# 1,938,516 undirected edges stored in both directions
+SNB_VERTICES, SNB_PADDED, SNB_EDGES = 65_645, 65_664, 3_877_032
+
+
+@pytest.mark.parametrize("program", ["forward", "backward", "pair"])
+def test_reach_program_compiles_for_v5e(program, one_chip):
+    """The device reach: a ``while`` over BFS levels, each an int8 product
+    of the frontier (or, two-sided, both frontiers) with the dense
+    adjacency (int32 accumulator), and the one scatter that builds the
+    adjacency from the CSR; both fit one chip."""
+    i32 = lambda shape: _spec(shape, one_chip, jnp.int32)
+    adj = _spec((SNB_PADDED, SNB_PADDED), one_chip, jnp.int8)
+    member = _spec((SNB_PADDED,), one_chip, jnp.bool_)
+    compiled = traversal_ops.reach_program.lower(
+        adj, i32((2 if program == "pair" else 1,)), member, i32(()), i32(()),
+        transpose=program == "backward").compile()
+    text = compiled.as_text()
+    assert re.search(r"\swhile\(", text)
+    assert "tpu_custom_call" not in text
+    assert _fits(compiled)
+    build = traversal_ops.dense_adjacency.lower(
+        i32((SNB_VERTICES + 1,)), i32((SNB_EDGES,)),
+        n_pad=SNB_PADDED).compile()
+    m = build.memory_analysis()
+    assert m.output_size_in_bytes == SNB_PADDED * SNB_PADDED
+    assert _fits(build)

@@ -15,7 +15,7 @@ from hypothesis import given, settings, strategies as st
 from repro.core import cost, optimizer, physical as ph, verify
 from repro.core.engine import GredoEngine
 from repro.core.schema import (JoinPred, Pattern, PatternVertex, Predicate,
-                               chain_pattern)
+                               Query, chain_pattern)
 from repro.core.storage import Database, DictColumn, Graph, RaggedColumn, Table
 from repro.data import m2bench
 
@@ -296,6 +296,72 @@ def test_vdev_mask_children_rejected():
                                        scan(db, "Persons")),)
     report = verify.verify_plan(masked, db)
     assert "V-DEV" in rules_of(report, verify.ERROR)
+
+
+def _reach_nodes(db: Database):
+    """The optimized plan of a 1..3-hop reach over Follows, its
+    DeviceReach and its GraphProject."""
+    q = Query(select=("b.pid", "e0.hops"), froms=(),
+              match=chain_pattern("Follows", ("a", "Persons", "Follows", "b",
+                                              "Persons", 1, 3)),
+              where=(Predicate("a.pid", "==", 3),))
+    dag = GredoEngine(db).optimized_plan(q)
+    nodes = list(verify._walk(dag))
+    return (dag, next(n for n in nodes if n.kind == "DeviceReach"),
+            next(n for n in nodes if n.kind == "GraphProject"))
+
+
+@pytest.mark.parametrize("case", ["ok", "over_budget", "mask_child",
+                                  "chain_plan", "edge_predicate",
+                                  "projected_attr", "pending_deltas"])
+def test_vdev_device_reach(case):
+    db = m2db()
+    dag, node, proj = _reach_nodes(db)
+    if case == "ok":
+        assert verify.verify_plan(dag, db).ok
+        assert node.budget >= cost.dense_adjacency_bytes(
+            db.graphs["Follows"].n_vertices)
+        return
+    if case == "pending_deltas":
+        db = m2bench.generate(sf=1)
+        dag, node, _ = _reach_nodes(db)
+        db.graphs["Follows"].insert_edges(
+            {"svid": np.array([0]), "tvid": np.array([1]),
+             **{k: db.graphs["Follows"].edges.col(k)[:1]
+                for k in db.graphs["Follows"].edges.columns
+                if k not in ("svid", "tvid")}})
+        # as planned after the write, at the graph's new epoch
+        node = ph.DeviceReach(node.graph, db.epoch_of(node.graph), node.pplan,
+                              node.budget)
+        report = verify.verify_plan(node, db)
+        assert report.ok and "V-DEV" in rules_of(report, verify.WARN)
+        return
+    bad, rule = node, "V-DEV"
+    if case == "over_budget":
+        bad = ph.DeviceReach(node.graph, node.epoch, node.pplan, budget=10)
+    elif case == "mask_child":
+        bad = ph.DeviceReach(node.graph, node.epoch, node.pplan, node.budget)
+        bad.children = (ph.SemiJoinMask(node.graph, node.epoch, "Persons",
+                                        "p", "Persons.id",
+                                        scan(db, "Persons")),)
+    elif case == "chain_plan":
+        flag = optimizer.DEVICE_MATCH
+        optimizer.DEVICE_MATCH = True
+        try:
+            chain = _device_node(db)
+        finally:
+            optimizer.DEVICE_MATCH = flag
+        bad = ph.DeviceReach(node.graph, node.epoch, chain.pplan, node.budget)
+    elif case == "edge_predicate":
+        import dataclasses
+        pplan = dataclasses.replace(node.pplan, deferred={
+            "e0": [Predicate("e0.hops", "==", 2)]})
+        bad, rule = ph.Reach(node.graph, node.epoch, pplan), "V-COL"
+    else:
+        bad = ph.GraphProject(proj.graph, proj.epoch, proj.pattern, proj.keep,
+                              {"e0": ["weight"]}, node)
+        rule = "V-COL"
+    assert rule in rules_of(verify.verify_plan(bad, db), verify.ERROR)
 
 
 def test_vann_stale_annotation_warns():
